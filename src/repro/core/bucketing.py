@@ -149,17 +149,23 @@ class BucketedEmbedderBackend(JaxEmbedderBackend):
 
     def prewarm(self, buckets: Iterable[Tuple[int, int]]) -> int:
         """Eagerly compile the given (B_bucket, S_bucket) shapes so serving
-        takes no compile stalls.  Returns how many were newly compiled."""
-        jnp = self._jnp
+        takes no compile stalls.  Returns how many were newly compiled.
+
+        Inputs are staged exactly the way serving stages them
+        (``_stage_chunk``: same host arrays, same device placement), so the
+        executable compiled here is the one serving looks up."""
         new = 0
         for bb, sb in buckets:
             key = (int(bb), int(sb))
             with self._bucket_lock:
                 if key in self._buckets:
                     continue
-            toks = jnp.zeros(key, jnp.int32)
-            mask = jnp.ones(key, jnp.float32)
-            self._embed(self.params, toks, mask).block_until_ready()
+            chunk = [Query(qid=-1, length=sb)] * key[0]
+            toks, mask, _, _ = self._stage_chunk(chunk, *key)
+            try:
+                self._embed(self.params, toks, mask).block_until_ready()
+            finally:
+                self._release_staging([key])
             # mark warm only AFTER the compile succeeds, so an interrupted
             # prewarm can be retried instead of silently no-op'ing
             with self._bucket_lock:
@@ -184,6 +190,10 @@ class BucketedEmbedderBackend(JaxEmbedderBackend):
                             np.zeros((bb, sb), np.float32)))
         return (self._jnp.asarray(toks), self._jnp.asarray(mask), real,
                 truncated)
+
+    def _release_staging(self, keys) -> None:
+        """Hand staged buckets back once their execution is done (fresh host
+        arrays here need no bookkeeping; the sharded backend's ring does)."""
 
     def _enqueue_chunks(self, queries: Sequence[Query]
                         ) -> List[Tuple[int, object]]:

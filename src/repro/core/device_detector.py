@@ -11,13 +11,16 @@ tests and the simulator can exercise every branch of Algorithm 2.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
 class DeviceInventory:
     npus: int            # accelerator instance slots (I in the paper)
     cpus: int            # CPU instance slots (J in the paper)
+    # the jax devices behind the slots, when probed from this process
+    npu_devices: Tuple = ()
+    cpu_devices: Tuple = ()
 
 
 @dataclass(frozen=True)
@@ -47,8 +50,12 @@ def detect(inventory: Optional[DeviceInventory] = None,
 
 
 def probe_jax_devices() -> DeviceInventory:
+    """This process's devices: the default backend's accelerators (TPU/GPU)
+    and the host CPU, which JAX always provides next to them."""
     import jax
 
-    accel = [d for d in jax.devices() if d.platform not in ("cpu",)]
+    accel = tuple(d for d in jax.devices() if d.platform != "cpu")
     # paper recommendation (§4.3): one CPU instance per machine
-    return DeviceInventory(npus=len(accel), cpus=1)
+    host = tuple(jax.devices("cpu")[:1])
+    return DeviceInventory(npus=len(accel), cpus=len(host),
+                           npu_devices=accel, cpu_devices=host)

@@ -20,6 +20,9 @@ from typing import Callable, Dict, List, Mapping, Sequence, Tuple
 
 import numpy as np
 
+# the depth a flat fit (alpha == 0) reports: unbounded under Eq. 12
+UNBOUNDED_DEPTH = 10 ** 9
+
 
 @dataclass(frozen=True)
 class LatencyFit:
@@ -36,7 +39,7 @@ class LatencyFit:
         if self.latency(1) > slo_s:
             return 0
         if self.alpha <= 0:
-            return 10 ** 9  # degenerate flat fit: unbounded under this model
+            return UNBOUNDED_DEPTH  # degenerate flat fit
         # epsilon guards exact-boundary float error ((1-0.4)/0.1 -> 5.999...)
         return int(np.floor((slo_s - self.beta) / self.alpha + 1e-9))
 

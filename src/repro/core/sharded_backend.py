@@ -5,12 +5,13 @@ the accelerator tier the lever behind concurrency-per-device; PR 2 made the
 hot path's shapes stable and enumerable (the bucketed (B, S) compile cache).
 This module spends that stability on the device side of the batch:
 
-* **mesh fan-out** — one embedding tier becomes a jax ``Mesh`` over N local
-  devices.  Every bucketed batch is data-parallel sharded over the mesh
-  using the serve-mode rules in ``repro.parallel.sharding``
-  (``serve_embed_shardings``: weights RESIDENT — no ``data``-axis FSDP
-  specs, so no per-batch weight all-gathers — batch over ``data``).  A
-  single-device mesh degrades to exactly the PR 2 bucketed behaviour.
+* **mesh fan-out** — one embedding tier becomes a jax ``Mesh`` over N
+  devices of one platform (the TPU chips, or the host CPU).  Every bucketed
+  batch is data-parallel sharded over the mesh (``serve_embed_shardings``:
+  weights RESIDENT and replicated, so no per-batch weight all-gathers —
+  batch over ``data``) and each device embeds its own rows
+  (``embed_step``).  A single-device mesh serves the same vectors as the
+  bucketed backend.
 * **bf16-resident serving weights** — ``dtype="bf16"`` casts the param tree
   ONCE at load and runs every trunk matmul in bf16; the ``pool_norm``
   epilogue always accumulates fp32 (see ``repro.kernels.pool_norm``), so
@@ -73,6 +74,45 @@ def _serve_devices(devices=None) -> list:
     return devices[:usable]
 
 
+def embed_step(cfg, mesh, compute_dtype, act_quant: bool, *,
+               donate: bool = False, on_trace: Optional[Callable] = None):
+    """The jitted serving step ``(params, tokens, mask) -> (B, D)`` on
+    ``mesh``: weights replicated, the batch split over the data axes, and
+    ``embedder.embed`` run per data shard under ``shard_map``.
+
+    Every device embeds its own rows with no collective.  Running the
+    embedder per shard is also what lets a multi-chip mesh use the TPU's
+    Mosaic kernels, which XLA cannot partition.  Kernels follow the mesh's
+    platform (``repro.kernels.placement``): a TPU mesh runs them compiled,
+    a CPU mesh runs their jnp references.  ``on_trace`` runs once per
+    trace (the backends' retrace counter).
+    """
+    import jax
+
+    from repro.models import embedder
+    from repro.parallel.sharding import serve_embed_shardings
+
+    if mesh.shape.get("model", 1) != 1:
+        raise ValueError(f"the serving mesh is data-parallel only; got "
+                         f"model axis {mesh.shape['model']}")
+    psh, bsh = serve_embed_shardings(mesh)
+
+    def local(p, toks, mask):
+        if on_trace is not None:
+            on_trace()
+        return embedder.embed(p, cfg, toks, mask,
+                              compute_dtype=compute_dtype,
+                              act_quant=act_quant)
+
+    # no collective runs inside, so there is no replication to type-check
+    # (the embedder's scan carries start from fresh zeros)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(psh.spec, bsh.spec, bsh.spec),
+                       out_specs=bsh.spec, check_vma=False)
+    return jax.jit(fn, in_shardings=(psh, bsh, bsh), out_shardings=bsh,
+                   donate_argnums=(1, 2) if donate else ())
+
+
 class ShardedEmbedderBackend(BucketedEmbedderBackend):
     """Bucketed embedder fanned out over a data-parallel device mesh.
 
@@ -104,7 +144,6 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
 
         from repro import perf_flags
         from repro.launch.mesh import make_serve_mesh
-        from repro.models import embedder
         from repro.models.quantize import serve_params, wants_act_quant
         from repro.parallel.sharding import dp_axes, serve_embed_shardings
 
@@ -120,6 +159,9 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
         if mesh is None:
             mesh = make_serve_mesh(_serve_devices(devices))
         self.mesh = mesh
+        # kernels follow this platform: compiled on "tpu", jnp references
+        # on "cpu" (see ``embed_step``)
+        self.platform = mesh.devices.flat[0].platform
         ndev = 1
         for a in dp_axes(mesh):
             ndev *= mesh.shape[a]
@@ -145,33 +187,26 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
         self.serve_dtype = cdt
         aq = wants_act_quant(dtype)
         self.act_quant = aq
-        self.name = (f"jax-sharded/{cfg.name}@{ndev}dev/{dtype}"
+        self.name = (f"jax-sharded/{cfg.name}@{ndev}{self.platform}/{dtype}"
                      + ("+donate" if donate else "")
                      + ("+async" if self.async_dispatch else ""))
 
         # (a) weights realised ONCE at load (cast / quantized) and laid out
         # resident on the mesh; dequant scales ride the tree as fp32 leaves
-        psh, bsh = serve_embed_shardings(
-            mesh, jax.eval_shape(lambda: served))
+        psh, self._batch_sharding = serve_embed_shardings(mesh)
         self.params = jax.device_put(served, psh)
-        self._batch_sharding = bsh
 
-        def _fn(p, toks, mask):
+        def count_trace():
             self.traces += 1          # python side effect: runs once per trace
-            return embedder.embed(p, cfg, toks, mask, compute_dtype=cdt,
-                                  act_quant=aq)
 
         # (b) donate the per-batch token/mask device buffers; on a backend
-        # where donation is unimplemented (this CPU container) the
-        # "not usable" warning is pure noise, so it is filtered ONCE and
-        # only there — on TPU/GPU a donation diagnostic stays visible
-        jit_kw = {}
-        if donate:
-            jit_kw["donate_argnums"] = (1, 2)
-            if jax.default_backend() == "cpu":
-                _filter_cpu_donation_warning()
-        self._embed = jax.jit(_fn, in_shardings=(psh, bsh, bsh),
-                              out_shardings=bsh, **jit_kw)
+        # where donation is unimplemented (the CPU) the "not usable"
+        # warning is pure noise, so it is filtered ONCE and only there — on
+        # TPU a donation diagnostic stays visible
+        if donate and self.platform == "cpu":
+            _filter_cpu_donation_warning()
+        self._embed = embed_step(cfg, mesh, cdt, aq, donate=donate,
+                                 on_trace=count_trace)
         self._jax = jax
 
         # reusable pinned host staging arrays: a small RING of pairs per

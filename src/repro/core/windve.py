@@ -12,11 +12,13 @@ is the DES in ``repro.core.simulator``): every query goes through the same
 semantics cannot diverge.
 
 Backends:
-* ``JaxEmbedderBackend`` — actually runs the bge/jina-style JAX embedder on
-  this host's CPU (the paper's CPU pool).
-* ``ModeledBackend``     — wall-clock sleeps per the calibrated DeviceModel
-  (stands in for the NPU/GPU pool on this accelerator-less container; on a
-  real TPU deployment this is replaced by the pjit'd embedder).
+* ``JaxEmbedderBackend`` — runs the bge/jina-style JAX embedder on the
+  process's default device; the serving tiers use its bucketed, sharded
+  descendant (``repro.core.sharded_backend``), placed on the TPU chips for
+  the accelerator tier and on the host CPU for the offload tier.
+* ``ModeledBackend``     — wall-clock sleeps per a calibrated DeviceModel
+  (the DES's devices, e.g. the paper's V100); used where a caller names
+  one (tests, DES calibration), never in place of a missing chip.
 
 Observability: ``add_batch_hook(fn)`` registers a first-class batch
 completion hook ``fn(tier_name, batch, service_latency_s)`` — the online
@@ -108,7 +110,8 @@ class ModeledBackend(Backend):
 
 
 class JaxEmbedderBackend(Backend):
-    """Real JAX embedder running on the host CPU.
+    """Real JAX embedder on the process's default device — the fixed-shape
+    baseline of the backend chain (bucketed, then sharded).
 
     Every batch is padded to the fixed ``max_tokens`` window, and every new
     *batch size* triggers a fresh jit trace (``traces`` counts them) — the

@@ -10,7 +10,8 @@ TPU adaptation of the attention hot-spot (DESIGN.md §6):
   and head_dim is 64/128.
 * GQA is expressed in the k/v index_map (kv_head = head // group_size), so
   grouped queries reuse the same k/v VMEM tile with no gather.
-* causal / sliding-window masks come from program-id iota — no mask tensor.
+* causal / sliding-window masks come from program-id iota — no mask tensor;
+  the per-example valid-key count ``kv_len`` is scalar-prefetched to SMEM.
 """
 from __future__ import annotations
 
@@ -23,12 +24,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.placement import dot_precision
+
 NEG_INF = -1e30
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, kvl_ref, o_ref, acc_ref, m_ref, d_ref,
+def _flash_kernel(kvl_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, d_ref,
                   *, scale: float, causal: bool, window: int,
                   sq: int, block_q: int, block_k: int, nk: int):
+    b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -41,33 +45,35 @@ def _flash_kernel(q_ref, k_ref, v_ref, kvl_ref, o_ref, acc_ref, m_ref, d_ref,
     q = q_ref[0, 0]                                      # (bq, hd)
     k = k_ref[0, 0]                                      # (bk, hd)
     v = v_ref[0, 0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        precision=dot_precision(q.dtype),
+                        preferred_element_type=jnp.float32) * scale
 
     qp = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     kp = ki * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     # per-example valid-key prefix (ragged batches: bucketed embedder pads
     # each row to the bucket; padded keys must not enter the softmax)
-    valid = (qp < sq) & (kp < kvl_ref[0, 0])
+    valid = (qp < sq) & (kp < kvl_ref[b])
     if causal:
         valid &= kp <= qp
     if window:
         valid &= kp > qp - window
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                  # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    d_ref[...] = d_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = (acc_ref[...] * corr[:, None]
+    d_ref[...] = d_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = (acc_ref[...] * corr
                     + jnp.dot(p.astype(v.dtype), v,
+                              precision=dot_precision(v.dtype),
                               preferred_element_type=jnp.float32))
     m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(d_ref[...], 1e-30)[:, None]
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(d_ref[...], 1e-30)
                        ).astype(o_ref.dtype)
 
 
@@ -81,9 +87,9 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``kv_len`` (optional, (B,) int32): per-example count of valid keys —
     keys at positions >= kv_len[b] are masked out (ragged/bucketed batches
     where each row is left-aligned and padded to the bucket).  Defaults to
-    all Sk keys valid.  On this container the kernel body executes via
-    interpret=True (CPU); on TPU pass interpret=False for the compiled MXU
-    path."""
+    all Sk keys valid.  ``interpret=True`` runs the kernel body under the
+    Pallas interpreter (any platform); ``interpret=False`` is the compiled
+    TPU kernel."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     assert H % KV == 0, "num_heads must be a multiple of num_kv_heads"
@@ -99,8 +105,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, 0)))
     if kv_len is None:
         kv_len = jnp.full((B,), Sk, jnp.int32)
-    # (B, 1) scalar-per-block in SMEM: one bound per batch row
-    kvl = jnp.minimum(kv_len.astype(jnp.int32), Sk).reshape(B, 1)
+    # one valid-key bound per batch row, scalar-prefetched into SMEM
+    kvl = jnp.minimum(kv_len.astype(jnp.int32), Sk)
 
     # the per-example kvl bound (clamped to the unpadded Sk) also masks the
     # block-padding key tail, so no separate `kp < Sk` guard is needed
@@ -110,25 +116,28 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     out = pl.pallas_call(
         kernel,
-        grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, hd),
-                         lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bk, hd),
-                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1, bk, hd),
-                         lambda b, h, qi, ki: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, qi, ki: (b, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd),
-                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, hd),
+                             lambda b, h, qi, ki, kvl: (b, h, qi, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, qi, ki, kvl: (b, h // G, ki, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, qi, ki, kvl: (b, h // G, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, bq, hd),
+                                   lambda b, h, qi, ki, kvl: (b, h, qi, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
+                pltpu.VMEM((bq, 1), jnp.float32),    # running max
+                pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
-            pltpu.VMEM((bq,), jnp.float32),      # running max
-            pltpu.VMEM((bq,), jnp.float32),      # running denominator
-        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-    )(q, k, v, kvl)
+    )(kvl, q, k, v)
     return out[:, :, :Sq]

@@ -1,4 +1,4 @@
-"""Backend-dispatching jit wrapper for flash-decode attention."""
+"""Placement-dispatching jit wrapper for flash-decode attention."""
 from __future__ import annotations
 
 import functools
@@ -7,20 +7,21 @@ import jax
 
 from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
 from repro.kernels.flash_decode.ref import decode_attention_ref
+from repro.kernels.placement import by_placement
 
 
 @functools.partial(jax.jit, static_argnames=("window", "backend", "block_k"))
 def flash_decode(q, k, v, kpos, pos, *, window: int = 0,
                  backend: str = "auto", block_k: int = 256):
+    kernel = functools.partial(flash_decode_pallas, window=window,
+                               block_k=block_k)
+    reference = functools.partial(decode_attention_ref, window=window)
     if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if backend == "pallas":
-        return flash_decode_pallas(q, k, v, kpos, pos, window=window,
-                                   block_k=block_k, interpret=False)
-    if backend == "interpret":
-        return flash_decode_pallas(q, k, v, kpos, pos, window=window,
-                                   block_k=block_k, interpret=True)
-    return decode_attention_ref(q, k, v, kpos, pos, window=window)
+        return by_placement(functools.partial(kernel, interpret=False),
+                            reference, q, k, v, kpos, pos)
+    if backend in ("pallas", "interpret"):
+        return kernel(q, k, v, kpos, pos, interpret=backend == "interpret")
+    return reference(q, k, v, kpos, pos)
 
 
 __all__ = ["flash_decode", "flash_decode_pallas", "decode_attention_ref"]

@@ -1,9 +1,11 @@
-"""Backend-dispatching jit wrappers for the fused int8 quant matmuls.
+"""Placement-dispatching jit wrappers for the fused int8 quant matmuls.
 
 ``_quant_matmul`` / ``_quant_matmul_w8a8`` are the unjitted impls (exposed
-so dispatch tests can record which route fires without fighting jit
+so dispatch tests can lower them for a chosen platform without fighting jit
 caches); ``quant_matmul`` / ``quant_matmul_w8a8`` are the jitted entries
-every serving call site uses.
+every serving call site uses.  ``auto`` runs the compiled Pallas kernel
+where the call is placed on a TPU and the jnp oracle elsewhere
+(``repro.kernels.placement``).
 """
 from __future__ import annotations
 
@@ -11,6 +13,7 @@ import functools
 
 import jax
 
+from repro.kernels.placement import by_placement
 from repro.kernels.quant_matmul import quant_matmul as _kmod
 from repro.kernels.quant_matmul import ref as _rmod
 from repro.kernels.quant_matmul.quant_matmul import (quantize_activations,
@@ -19,26 +22,15 @@ from repro.kernels.quant_matmul.quant_matmul import (quantize_activations,
 from repro.kernels.quant_matmul.ref import quant_matmul_ref, w8a8_matmul_ref
 
 
-def _resolve_backend(backend: str) -> str:
-    """``auto`` routes to the Pallas kernel exactly when running on a TPU
-    backend (where int8 VMEM tiles pay off); everywhere else the jnp oracle
-    is the same contract, lowered through XLA."""
-    if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "ref"
-    return backend
-
-
 def _quant_matmul(x, w8, scale, *, backend: str = "auto", block_m: int = 128,
                   block_n: int = 128, block_k: int = 128):
-    backend = _resolve_backend(backend)
-    if backend == "pallas":
-        return _kmod.quant_matmul_pallas(x, w8, scale, block_m=block_m,
-                                         block_n=block_n, block_k=block_k,
-                                         interpret=False)
-    if backend == "interpret":
-        return _kmod.quant_matmul_pallas(x, w8, scale, block_m=block_m,
-                                         block_n=block_n, block_k=block_k,
-                                         interpret=True)
+    kernel = functools.partial(_kmod.quant_matmul_pallas, block_m=block_m,
+                               block_n=block_n, block_k=block_k)
+    if backend == "auto":
+        return by_placement(functools.partial(kernel, interpret=False),
+                            _rmod.quant_matmul_ref, x, w8, scale)
+    if backend in ("pallas", "interpret"):
+        return kernel(x, w8, scale, interpret=backend == "interpret")
     return _rmod.quant_matmul_ref(x, w8, scale)
 
 
@@ -59,18 +51,17 @@ def _quant_matmul_w8a8(x, w8, w_scale, *, backend: str = "auto",
                        block_m: int = 128, block_n: int = 128,
                        block_k: int = 128):
     x8, x_scale = quantize_activations(x)
-    backend = _resolve_backend(backend)
-    if backend == "pallas":
-        return _kmod.w8a8_matmul_pallas(x8, w8, x_scale, w_scale,
-                                        block_m=block_m, block_n=block_n,
-                                        block_k=block_k, out_dtype=x.dtype,
-                                        interpret=False)
-    if backend == "interpret":
-        return _kmod.w8a8_matmul_pallas(x8, w8, x_scale, w_scale,
-                                        block_m=block_m, block_n=block_n,
-                                        block_k=block_k, out_dtype=x.dtype,
-                                        interpret=True)
-    return _rmod.w8a8_matmul_ref(x8, w8, x_scale, w_scale, out_dtype=x.dtype)
+    kernel = functools.partial(_kmod.w8a8_matmul_pallas, block_m=block_m,
+                               block_n=block_n, block_k=block_k,
+                               out_dtype=x.dtype)
+    reference = functools.partial(_rmod.w8a8_matmul_ref, out_dtype=x.dtype)
+    if backend == "auto":
+        return by_placement(functools.partial(kernel, interpret=False),
+                            reference, x8, w8, x_scale, w_scale)
+    if backend in ("pallas", "interpret"):
+        return kernel(x8, w8, x_scale, w_scale,
+                      interpret=backend == "interpret")
+    return reference(x8, w8, x_scale, w_scale)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "block_m", "block_n",

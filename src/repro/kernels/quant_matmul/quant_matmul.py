@@ -33,15 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _default_interpret() -> bool:
-    """Interpret everywhere except a real TPU backend (compiled there).
-
-    Mirrors the ``auto`` route in ``ops``: the Mosaic-compiled path only
-    exists on TPU; on CPU/GPU hosts the kernels run under the Pallas
-    interpreter so tests and smoke benches exercise the same code path.
-    """
-    return jax.default_backend() != "tpu"
+from repro.kernels.placement import dot_precision
 
 
 def quantize_activations(x: jax.Array):
@@ -72,28 +64,25 @@ def _quant_matmul_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, nk: int):
 
     x = x_ref[...]                                   # (bm, bk) activations
     w = w_ref[...].astype(x.dtype)                   # (bk, bn) int8 widened
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(x, w, precision=dot_precision(x.dtype),
+                            preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        scale = s_ref[...].astype(jnp.float32)       # (bn,) per out channel
-        o_ref[...] = (acc_ref[...] * scale[None, :]).astype(o_ref.dtype)
+        scale = s_ref[...].astype(jnp.float32)       # (1, bn) per out channel
+        o_ref[...] = (acc_ref[...] * scale).astype(o_ref.dtype)
 
 
 def quant_matmul_pallas(x: jax.Array, w8: jax.Array, scale: jax.Array, *,
                         block_m: int = 128, block_n: int = 128,
                         block_k: int = 128, out_dtype=None,
-                        interpret: bool | None = None) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """x: (..., K) float; w8: (K, N) int8; scale: (N,) -> (..., N).
 
-    ``interpret=None`` resolves from the active backend (compiled on TPU,
-    interpreted elsewhere) — never default to the interpreter on hardware
-    that has the real lowering.
+    Compiled unless ``interpret=True`` asks for the Pallas interpreter.
     """
     if w8.dtype != jnp.int8:
         raise TypeError(f"quantized weights must be int8, got {w8.dtype}")
-    if interpret is None:
-        interpret = _default_interpret()
     *lead, K = x.shape
     N = w8.shape[1]
     out_dtype = x.dtype if out_dtype is None else out_dtype
@@ -106,15 +95,18 @@ def quant_matmul_pallas(x: jax.Array, w8: jax.Array, scale: jax.Array, *,
         xf = jnp.pad(xf, ((0, pm), (0, pk)))
     if pk or pn:
         w8 = jnp.pad(w8, ((0, pk), (0, pn)))
+    # scales ride as 2-D (1, N) rows: Mosaic tiles 1-D operands differently
+    # from XLA's layout and refuses them
+    scale = scale.reshape(1, N)
     if pn:
-        scale = jnp.pad(scale, (0, pn), constant_values=1.0)
+        scale = jnp.pad(scale, ((0, 0), (0, pn)), constant_values=1.0)
     out = pl.pallas_call(
         functools.partial(_quant_matmul_kernel, nk=nk),
         grid=(nm, nn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), out_dtype),
@@ -139,34 +131,36 @@ def _w8a8_matmul_kernel(x_ref, w_ref, xs_ref, ws_ref, o_ref, acc_ref, *,
 
     @pl.when(k == nk - 1)
     def _epilogue():
-        xs = xs_ref[...].astype(jnp.float32)         # (bm,) per activation row
-        ws = ws_ref[...].astype(jnp.float32)         # (bn,) per out channel
+        xs = xs_ref[...].astype(jnp.float32)         # (bm, 1) per act row
+        ws = ws_ref[...].astype(jnp.float32)         # (1, bn) per out channel
         o_ref[...] = (acc_ref[...].astype(jnp.float32)
-                      * xs[:, None] * ws[None, :]).astype(o_ref.dtype)
+                      * xs * ws).astype(o_ref.dtype)
 
 
 def w8a8_matmul_pallas(x8: jax.Array, w8: jax.Array, x_scale: jax.Array,
                        w_scale: jax.Array, *, block_m: int = 128,
                        block_n: int = 128, block_k: int = 128,
                        out_dtype=jnp.float32,
-                       interpret: bool | None = None) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """x8: (..., K) int8; w8: (K, N) int8; x_scale: x8.shape[:-1];
     w_scale: (N,) -> (..., N) float.
 
     Accumulates int32 in VMEM scratch across the K grid axis and dequantizes
     once in the epilogue by ``x_scale[:, None] * w_scale[None, :]`` — neither
-    operand is ever widened to float inside the tile.
+    operand is ever widened to float inside the tile.  Compiled unless
+    ``interpret=True``.
     """
     if x8.dtype != jnp.int8:
         raise TypeError(f"quantized activations must be int8, got {x8.dtype}")
     if w8.dtype != jnp.int8:
         raise TypeError(f"quantized weights must be int8, got {w8.dtype}")
-    if interpret is None:
-        interpret = _default_interpret()
     *lead, K = x8.shape
     N = w8.shape[1]
     xq = x8.reshape(-1, K)
-    xs = x_scale.reshape(-1)
+    # both scale vectors ride 2-D, (M, 1) and (1, N), like the weight-only
+    # kernel's: Mosaic refuses 1-D operands
+    xs = x_scale.reshape(-1, 1)
+    w_scale = w_scale.reshape(1, N)
     M = xq.shape[0]
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
     nm, nn, nk = -(-M // bm), -(-N // bn), -(-K // bk)
@@ -174,19 +168,19 @@ def w8a8_matmul_pallas(x8: jax.Array, w8: jax.Array, x_scale: jax.Array,
     if pm or pk:
         xq = jnp.pad(xq, ((0, pm), (0, pk)))
     if pm:
-        xs = jnp.pad(xs, (0, pm), constant_values=1.0)
+        xs = jnp.pad(xs, ((0, pm), (0, 0)), constant_values=1.0)
     if pk or pn:
         w8 = jnp.pad(w8, ((0, pk), (0, pn)))
     if pn:
-        w_scale = jnp.pad(w_scale, (0, pn), constant_values=1.0)
+        w_scale = jnp.pad(w_scale, ((0, 0), (0, pn)), constant_values=1.0)
     out = pl.pallas_call(
         functools.partial(_w8a8_matmul_kernel, nk=nk),
         grid=(nm, nn, nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bm,), lambda i, j, k: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), out_dtype),

@@ -5,10 +5,6 @@ jax device state.  The dry-run entrypoint (launch/dryrun.py) sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` BEFORE importing jax
 so these meshes can be built on the CPU-only container.
 
-``jax.sharding.AxisType`` (and ``jax.make_mesh``'s ``axis_types`` kwarg)
-only exist on newer jax releases; on older installs the meshes are built
-without explicit axis types, which is the same default behaviour.
-
 Every builder validates the requested shape against the available device
 count up front: jax's own failure mode is an opaque reshape error from deep
 inside ``make_mesh`` ("cannot reshape array of size 1 into shape (16,16)"),
@@ -22,11 +18,7 @@ import math
 from typing import List, Optional, Sequence
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:          # older jax: no AxisType / axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _require(needed: int, available: int, what: str) -> None:
@@ -44,14 +36,8 @@ def _mesh(shape, axes, devices=None):
         else jax.local_device_count()
     _require(needed, available, f"mesh {dict(zip(axes, shape))}")
     kw = {} if devices is None else {"devices": devices}
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes),
-                                 **kw)
-        except TypeError:    # AxisType exists but make_mesh predates kwarg
-            pass
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -101,13 +87,21 @@ def make_replica_meshes(hosts: int = 1, replicas: int = 1,
     serve mesh.  A pool that does not split evenly raises a ``ValueError``
     naming required vs available counts (never jax's reshape error).
     """
+    return [make_serve_mesh(g) for g in replica_groups(hosts, replicas,
+                                                       devices)]
+
+
+def replica_groups(hosts: int = 1, replicas: int = 1,
+                   devices: Optional[Sequence] = None) -> List[List]:
+    """The device groups :func:`make_replica_meshes` builds its meshes
+    over: ``hosts * replicas`` equal contiguous slices of the pool."""
     if hosts < 1 or replicas < 1:
         raise ValueError(f"hosts and replicas must be >= 1, "
                          f"got {hosts}x{replicas}")
     devices = list(jax.local_devices() if devices is None else devices)
     groups = hosts * replicas
     if groups == 1:
-        return [make_serve_mesh(devices)]
+        return [devices]
     _require(groups, len(devices),
              f"replica topology {hosts} host(s) x {replicas} replica(s)")
     if len(devices) % groups:
@@ -116,16 +110,9 @@ def make_replica_meshes(hosts: int = 1, replicas: int = 1,
             f"{hosts} host(s) x {replicas} replica(s) = {groups} groups; "
             f"each replica needs an equal device group")
     per = len(devices) // groups
-    return [make_serve_mesh(devices[g * per:(g + 1) * per])
-            for g in range(groups)]
+    return [devices[g * per:(g + 1) * per] for g in range(groups)]
 
 
 def mesh_context(mesh):
-    """Context manager enabling bare-PartitionSpec sharding constraints.
-
-    ``jax.set_mesh`` on new jax; on older releases entering the ``Mesh``
-    itself installs the equivalent resource environment.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager enabling bare-PartitionSpec sharding constraints."""
+    return jax.set_mesh(mesh)

@@ -9,7 +9,7 @@ Attention uses a pure-JAX blockwise flash implementation (two-level chunk scan
 with online softmax) so 32k-token prefill never materialises an S x S score
 matrix.  The Pallas TPU kernel in ``repro.kernels.flash_attention`` implements
 the same math with explicit VMEM BlockSpecs; ``repro.kernels.*.ops`` selects
-between them by backend.
+between them by the platform a computation is placed on.
 """
 from __future__ import annotations
 
@@ -299,10 +299,11 @@ def attn_forward(
     ``FLAGS.attn_kernel`` selects the implementation: the chunked pure-JAX
     flash path (baseline), or the Pallas TPU kernel
     (``repro.kernels.flash_attention``) — "auto" picks the kernel exactly
-    when running on a TPU backend.  The kernel route assumes contiguous
-    [0, S) positions (true for every full-sequence caller here) and turns a
-    per-example ``kv_mask`` into prefix lengths, which is what the
-    embedder's left-aligned padding produces.
+    where the computation is placed on a TPU (``repro.kernels.placement``).
+    The kernel route assumes contiguous [0, S) positions (true for every
+    full-sequence caller here) and turns a per-example ``kv_mask`` into
+    prefix lengths, which is what the embedder's left-aligned padding
+    produces.
     """
     kv_src = x if kv_x is None else kv_x
     kv_pos = positions if kv_positions is None else kv_positions
@@ -310,27 +311,35 @@ def attn_forward(
     if cfg.rope_theta:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, kv_pos, cfg.rope_theta)
+    window = cfg.sliding_window if causal else 0
+
+    def jnp_route(q, k, v, *mask):
+        return flash_attention_jnp(q, k, v, positions, kv_pos, causal=causal,
+                                   window=window,
+                                   kv_mask=mask[0] if mask else None)
+
+    def kernel_route(q, k, v, *mask, interpret: bool = False):
+        from repro.kernels.flash_attention.ops import flash_attention
+        kv_len = None
+        if mask:
+            kv_len = jnp.sum(mask[0] != 0, axis=-1).astype(jnp.int32)
+        out = flash_attention(
+            jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
+            jnp.moveaxis(v, 1, 2), causal=causal, window=window,
+            backend="interpret" if interpret else "pallas", kv_len=kv_len)
+        return jnp.moveaxis(out, 2, 1)
 
     from repro.perf_flags import FLAGS
 
+    args = (q, k, v) + (() if kv_mask is None else (kv_mask,))
     backend = FLAGS.attn_kernel
     if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
-    if backend in ("pallas", "interpret"):
-        from repro.kernels.flash_attention.ops import flash_attention
-        kv_len = None
-        if kv_mask is not None:
-            kv_len = jnp.sum(kv_mask != 0, axis=-1).astype(jnp.int32)
-        out = flash_attention(
-            jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
-            jnp.moveaxis(v, 1, 2), causal=causal,
-            window=cfg.sliding_window if causal else 0,
-            backend=backend, kv_len=kv_len)
-        out = jnp.moveaxis(out, 2, 1)
+        from repro.kernels.placement import by_placement
+        out = by_placement(kernel_route, jnp_route, *args)
+    elif backend in ("pallas", "interpret"):
+        out = kernel_route(*args, interpret=backend == "interpret")
     else:
-        out = flash_attention_jnp(
-            q, k, v, positions, kv_pos, causal=causal,
-            window=cfg.sliding_window if causal else 0, kv_mask=kv_mask)
+        out = jnp_route(*args)
     y = dense_apply(p, "wo", out.reshape(*x.shape[:-1], -1),
                     act_quant=act_quant)
     if return_kv:
@@ -415,13 +424,6 @@ def attn_decode_sharded(p: Params, cfg: ModelConfig, x1: jax.Array,
     which rewrites the FULL cache through a select (+ copies) every layer —
     measured 1.3 TB/step on qwen2-72b decode_32k vs ~11 GB here."""
     from jax.sharding import PartitionSpec as P
-    import functools as _ft
-    try:
-        from jax import shard_map as _sm
-        shard_map = _ft.partial(_sm, check_vma=False)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm_old
-        shard_map = _ft.partial(_sm_old, check_rep=False)
 
     hd = cfg.resolved_head_dim
     H, KV = cfg.num_heads, cfg.num_kv_heads
@@ -463,8 +465,8 @@ def attn_decode_sharded(p: Params, cfg: ModelConfig, x1: jax.Array,
 
     b = dp if B > 1 else None
     seq = comb if len(comb) > 1 else comb[0]
-    out, nk, nv = shard_map(
-        local_fn, mesh=mesh,
+    out, nk, nv = jax.shard_map(
+        local_fn, mesh=mesh, check_vma=False,
         in_specs=(P(b, None, None, None), P(b, None, None, None),
                   P(b, None, None, None), P(b, seq, None, None),
                   P(b, seq, None, None), P(seq), P()),
@@ -622,23 +624,9 @@ def apply_moe(p: Params, cfg: ModelConfig, x: jax.Array) -> Tuple[jax.Array, jax
 
 
 def _mesh_axis_names():
-    """Axis names of the mesh currently in context, () if none.
-
-    ``jax.sharding.get_abstract_mesh`` on new jax; older releases stash the
-    context mesh in thread resources when a ``Mesh`` is entered.
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        am = get_am()
-        if am is None or getattr(am, "empty", True):
-            return ()
-        return tuple(am.axis_names)
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return () if m is None or m.empty else tuple(m.axis_names)
-    except Exception:  # pragma: no cover - internals moved; stay a no-op
-        return ()
+    """Axis names of the mesh currently in context, () if none."""
+    am = jax.sharding.get_abstract_mesh()
+    return () if am.empty else tuple(am.axis_names)
 
 
 def _moe_constrain(x: jax.Array, tail_spec) -> jax.Array:

@@ -151,19 +151,18 @@ def param_shardings(mesh, params_shape, mode: str = "train") -> Any:
                         param_pspecs(mesh, params_shape, mode))
 
 
-def serve_embed_shardings(mesh, params_shape) -> Tuple[Any, NamedSharding]:
-    """(param shardings, batch sharding) for the data-parallel embed path.
+def serve_embed_shardings(mesh) -> Tuple[NamedSharding, NamedSharding]:
+    """(param sharding, batch sharding) for the data-parallel embed path.
 
-    Serve-mode param rules (weights RESIDENT: no ``data``-axis FSDP specs, so
-    per-batch weight all-gathers never enter the service-time term the
-    paper's Eq. 12 prices) + the (B, S) token/mask batch sharded over the
-    data axes.  The same pair shards the (B, D) output, whose trailing dim
-    is always replicated.
+    Weights are RESIDENT and replicated on every device of the serving
+    mesh, so per-batch weight all-gathers never enter the service-time term
+    the paper's Eq. 12 prices; the (B, S) token/mask batch is sharded over
+    the data axes.  The batch sharding also shards the (B, D) output, whose
+    trailing dim is always replicated.
     """
     dp = dp_axes(mesh)
     b = dp if len(dp) > 1 else (dp[0] if dp else None)
-    batch = NamedSharding(mesh, P(b, None))
-    return param_shardings(mesh, params_shape, mode="serve"), batch
+    return NamedSharding(mesh, P()), NamedSharding(mesh, P(b, None))
 
 
 # ---------------------------------------------------------------------------

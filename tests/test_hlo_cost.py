@@ -13,18 +13,12 @@ def _compile(f, *args):
     return jax.jit(f).lower(*args).compile()
 
 
-def _xla_cost(compiled):
-    """cost_analysis() returns a per-device list on older jax, a dict now."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def test_matches_xla_on_loop_free():
     x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     w = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     c = _compile(lambda x, w: jnp.tanh(x @ w), x, w)
     ours = analyse_hlo(c.as_text()).flops
-    xla = _xla_cost(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert ours == pytest.approx(xla, rel=0.05)
 
 
@@ -46,7 +40,7 @@ def test_scan_multiplied_by_trip_count():
     f_unroll = analyse_hlo(c_unroll.as_text()).flops
     # ours: scan == unrolled; XLA's builtin: scan == unrolled / 10
     assert f_scan == pytest.approx(f_unroll, rel=0.05)
-    assert _xla_cost(c_scan)["flops"] == \
+    assert c_scan.cost_analysis()["flops"] == \
         pytest.approx(f_unroll / 10, rel=0.05)
 
 

@@ -474,53 +474,50 @@ def test_w8a8_block_size_invariance():
 
 
 # ------------------------------------------------- quant dispatch ----------
-def test_quant_interpret_default_resolves_from_backend(monkeypatch):
-    """Satellite: quant_matmul_pallas / w8a8_matmul_pallas must not default
-    to the interpreter on a TPU backend — interpret=None resolves compiled
-    there and interpreted everywhere else."""
+def test_quant_interpret_default_resolves_from_backend():
+    """Satellite: quant_matmul_pallas / w8a8_matmul_pallas never default to
+    the interpreter — a caller gets the compiled kernel unless it asks for
+    ``interpret=True``, whatever the process's default backend is."""
     import importlib
+    import inspect
 
     # the package re-exports the jitted entry under the same name, so the
     # kernel MODULE must be resolved explicitly
     kmod = importlib.import_module("repro.kernels.quant_matmul.quant_matmul")
 
-    assert kmod._default_interpret() is (jax.default_backend() != "tpu")
-    monkeypatch.setattr(kmod.jax, "default_backend", lambda: "tpu")
-    assert kmod._default_interpret() is False
-    monkeypatch.setattr(kmod.jax, "default_backend", lambda: "cpu")
-    assert kmod._default_interpret() is True
+    for fn in (kmod.quant_matmul_pallas, kmod.w8a8_matmul_pallas):
+        assert inspect.signature(fn).parameters["interpret"].default is False
+    assert not hasattr(kmod, "_default_interpret")
 
 
-def test_quant_ops_auto_routes_pallas_compiled_on_tpu(monkeypatch):
-    """The ops auto route on a (mocked) TPU backend must call the Pallas
-    kernel with interpret=False — the TPU path can never silently run
-    interpreted — and the ref oracle elsewhere."""
+def _lowered_for(fn, platform, *args):
+    """StableHLO text of ``fn`` lowered for ``platform`` (no device needed:
+    this steers placement the way a jit on that platform's devices does)."""
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=(platform,)).as_text()
+
+
+def test_quant_ops_auto_routes_pallas_compiled_on_tpu():
+    """The ops auto route follows placement: lowered for a TPU it holds the
+    compiled Mosaic kernel (a ``tpu_custom_call`` — interpret mode would
+    lower to plain HLO loops), lowered for the CPU it holds no kernel and
+    computes the ref oracle."""
     from repro.kernels.quant_matmul import ops as qm_ops
 
-    seen = []
-    monkeypatch.setattr(qm_ops._kmod, "quant_matmul_pallas",
-                        lambda x, w8, s, interpret, **kw:
-                        seen.append(("w8-pallas", interpret)) or x)
-    monkeypatch.setattr(qm_ops._kmod, "w8a8_matmul_pallas",
-                        lambda x8, w8, xs, ws, interpret, **kw:
-                        seen.append(("w8a8-pallas", interpret)) or x8)
-    monkeypatch.setattr(qm_ops._rmod, "quant_matmul_ref",
-                        lambda *a, **kw: seen.append(("w8-ref", None)) or a[0])
-    monkeypatch.setattr(qm_ops._rmod, "w8a8_matmul_ref",
-                        lambda *a, **kw: seen.append(("w8a8-ref", None))
-                        or a[0])
-    x = jnp.ones((4, 32))
-    w8 = jnp.zeros((32, 16), jnp.int8)
-    s = jnp.ones((16,))
-
-    monkeypatch.setattr(qm_ops.jax, "default_backend", lambda: "tpu")
-    qm_ops._quant_matmul(x, w8, s)
-    qm_ops._quant_matmul_w8a8(x, w8, s)
-    monkeypatch.setattr(qm_ops.jax, "default_backend", lambda: "cpu")
-    qm_ops._quant_matmul(x, w8, s)
-    qm_ops._quant_matmul_w8a8(x, w8, s)
-    assert seen == [("w8-pallas", False), ("w8a8-pallas", False),
-                    ("w8-ref", None), ("w8a8-ref", None)]
+    x = jax.random.normal(KEY, (4, 32))
+    w8 = jax.random.randint(KEY, (32, 16), -127, 128, jnp.int32
+                            ).astype(jnp.int8)
+    s = jnp.full((16,), 0.02)
+    for fn in (qm_ops._quant_matmul, qm_ops._quant_matmul_w8a8):
+        assert "tpu_custom_call" in _lowered_for(fn, "tpu", x, w8, s)
+        assert "tpu_custom_call" not in _lowered_for(fn, "cpu", x, w8, s)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(qm_ops._quant_matmul)(x, w8, s)),
+        np.asarray(quant_matmul_ref(x, w8, s)), atol=1e-6)
+    x8, xs = quantize_activations(x)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(qm_ops._quant_matmul_w8a8)(x, w8, s)),
+        np.asarray(w8a8_matmul_ref(x8, w8, xs, s)), atol=1e-6)
 
 
 def test_w8a8_ops_backend_dispatch():

@@ -403,7 +403,7 @@ for leaf in jax.tree.leaves(be.params):
     assert len(leaf.sharding.device_set) == 8
     assert leaf.sharding.is_fully_replicated, leaf.sharding
 # the batch shards over data: 8 distinct shards, one row-block each
-_, bsh = serve_embed_shardings(be.mesh, jax.eval_shape(lambda: be.params))
+_, bsh = serve_embed_shardings(be.mesh)
 tok = jax.device_put(np.zeros((16, 32), np.int32), bsh)
 assert len({s.device for s in tok.addressable_shards}) == 8
 assert tok.addressable_shards[0].data.shape == (2, 32)
