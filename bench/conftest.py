@@ -1,0 +1,101 @@
+"""CPU fixtures for the benchmark's own tests.
+
+The harness runs here at a small width of the program's configurations
+(``small_program``), from a copy of ``bench/`` whose configuration files
+state that width and whose mixes run at a rate the host CPU serves
+(``bench_root``).  JAX's persistent compile cache stays off.
+"""
+import json
+import os
+import shutil
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+ROOT = Path(__file__).resolve().parents[1]
+for _p in (str(ROOT), str(ROOT / "src")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import pytest  # noqa: E402
+
+# the program's configurations at a width the CPU tests can run: two
+# layers, every width cut, multi-head attention as at full width
+SMALL = dict(num_hidden_layers=2, hidden_size=128, num_attention_heads=4,
+             head_dim=32, intermediate_size=256, vocab_size=512)
+
+
+@pytest.fixture(autouse=True)
+def no_compile_cache(monkeypatch, tmp_path):
+    """``enable_compile_cache`` takes a set ``JAX_COMPILATION_CACHE_DIR``
+    as JAX's own and configures nothing, so the cache stays off; the
+    harness's zero minimum compile time is put back afterwards."""
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    before = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", before)
+
+
+# the queue depth every tier gets in the CPU tests
+DEPTH = 256
+# limits at the small width, from CPU runs of both cells there: bf16
+# serving reads bias 0.0015-0.0017 and a widest gap of 0.0065-0.0067, the
+# int8 control bias 0.0049-0.0051 (gap 0.0085), the planted faults a
+# widest gap of 0.31-1.29; the chip's limits are PERF.md's
+SMALL_LIMITS = {"bias": 0.003, "max_l2_gap": 0.05}
+
+
+@pytest.fixture
+def small_program(monkeypatch):
+    """``build_engine`` serves the small width of each configuration, with
+    a fixed depth per tier: an Eq. 12 sweep timed on a loaded test host
+    can refuse its own fit, which says nothing about the harness."""
+    from repro.configs import get_config
+    from repro.core.estimator import fit_latency
+    from repro.launch import serve
+
+    def calibrate(name, profile, slo, points):
+        profile(points[0])           # runs the tier once, as a sweep would
+        return DEPTH, fit_latency([1, DEPTH], [0.01, slo])
+
+    def small(name):
+        return get_config(name).replace(
+            num_layers=SMALL["num_hidden_layers"],
+            d_model=SMALL["hidden_size"],
+            num_heads=SMALL["num_attention_heads"],
+            num_kv_heads=SMALL["num_attention_heads"],
+            head_dim=SMALL["head_dim"], d_ff=SMALL["intermediate_size"],
+            vocab_size=SMALL["vocab_size"])
+
+    monkeypatch.setattr(serve, "get_config", small)
+    monkeypatch.setattr(serve, "calibrate", calibrate)
+
+
+def write_json(path: Path, obj) -> None:
+    path.parent.mkdir(parents=True, exist_ok=True)
+    path.write_text(json.dumps(obj, indent=1))
+
+
+@pytest.fixture
+def bench_root(tmp_path):
+    """A checkout holding ``BENCHMARK.json`` and a copy of ``bench/`` at the
+    small width and its limits, with short warm-ups and a low open-loop
+    rate."""
+    root = tmp_path / "checkout"
+    shutil.copytree(ROOT / "bench", root / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    for f in (root / "bench" / "configs").glob("*.json"):
+        cfg = json.loads(f.read_text())
+        cfg.update(SMALL, limits=SMALL_LIMITS)
+        write_json(f, cfg)
+    for f in (root / "bench" / "traffic").glob("*.json"):
+        mix = json.loads(f.read_text())
+        mix["warmup_s"] = 0.5
+        if mix["loop"] == "open":
+            mix["rate_qps"] = 200
+        write_json(f, mix)
+    return root
