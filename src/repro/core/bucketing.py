@@ -33,7 +33,7 @@ from typing import Callable, Iterable, List, Sequence, Tuple
 import numpy as np
 
 from repro.core.routing import Query
-from repro.core.telemetry import Telemetry
+from repro.core.telemetry import NO_SPANS, Telemetry
 from repro.core.windve import JaxEmbedderBackend
 
 
@@ -177,19 +177,23 @@ class BucketedEmbedderBackend(JaxEmbedderBackend):
     def _qlen(q: Query) -> int:
         return len(q.payload) if q.payload is not None else q.length
 
-    def _stage_chunk(self, chunk: Sequence[Query], bb: int, sb: int):
+    def _stage_chunk(self, chunk: Sequence[Query], bb: int, sb: int,
+                     spans=NO_SPANS):
         """Tokenize one chunk into (bb, sb) device-ready inputs.
 
         Returns (tokens, mask, real_tokens, truncated).  The sharded backend
         overrides this with its staging-ring + mesh-sharded transfer; here
         fresh host arrays are handed straight to jit.  Padding rows beyond
-        the chunk stay all-zero (dropped by pooling).
+        the chunk stay all-zero (dropped by pooling).  ``spans`` times the
+        ``tokenize`` and ``device_put`` phases.
         """
-        toks, mask, real, truncated = self._tokenize(
-            chunk, sb, out=(np.zeros((bb, sb), np.int32),
-                            np.zeros((bb, sb), np.float32)))
-        return (self._jnp.asarray(toks), self._jnp.asarray(mask), real,
-                truncated)
+        with spans.span("tokenize"):
+            toks, mask, real, truncated = self._tokenize(
+                chunk, sb, out=(np.zeros((bb, sb), np.int32),
+                                np.zeros((bb, sb), np.float32)))
+        with spans.span("device_put"):
+            return (self._jnp.asarray(toks), self._jnp.asarray(mask), real,
+                    truncated)
 
     def _release_staging(self, keys) -> None:
         """Hand staged buckets back once their execution is done (fresh host
@@ -201,31 +205,58 @@ class BucketedEmbedderBackend(JaxEmbedderBackend):
         decompose the batch (``_batch_plan``), bucket each chunk's own
         sequence length, stage (``_stage_chunk``), count, and enqueue the
         jit execution.  Returns [(chunk_len, device_result), ...] in query
-        order; results are fetched by the caller (sync or deferred)."""
+        order; results are fetched by the caller (sync or deferred, through
+        ``_fetch``).  The whole is the tier's ``stage`` span, with
+        ``tokenize``/``device_put``/``dispatch`` spans per chunk."""
         handles: List[Tuple[int, object]] = []
-        start = 0
-        for bb in self._batch_plan(len(queries)):
-            chunk = queries[start:start + bb]
-            start += len(chunk)
-            # pad only to this chunk's own bucket; truncation still happens
-            # at the global max_tokens cap, exactly like the fixed backend
-            longest = max(min(self._qlen(q), self.max_tokens) for q in chunk)
-            sb = bucket_length(longest, self.min_seq_bucket, self.max_tokens)
-            toks, mask, real, truncated = self._stage_chunk(chunk, bb, sb)
-            self._record_truncations(truncated)
-            with self._bucket_lock:
-                if (bb, sb) in self._buckets:
-                    self.bucket_hits += 1
-                else:
-                    self._buckets.add((bb, sb))
-                self.real_tokens += real
-                self.padded_tokens += bb * sb - real
-            handles.append((len(chunk), self._embed(self.params, toks, mask)))
+        spans = self._spans()
+        with spans.span("stage", spans.batch):
+            start = 0
+            for bb in self._batch_plan(len(queries)):
+                chunk = queries[start:start + bb]
+                start += len(chunk)
+                # pad only to this chunk's own bucket; truncation still
+                # happens at the global max_tokens cap, exactly like the
+                # fixed backend
+                longest = max(min(self._qlen(q), self.max_tokens)
+                              for q in chunk)
+                sb = bucket_length(longest, self.min_seq_bucket,
+                                   self.max_tokens)
+                toks, mask, real, truncated = self._stage_chunk(chunk, bb, sb,
+                                                                spans)
+                self._record_truncations(truncated)
+                with self._bucket_lock:
+                    if (bb, sb) in self._buckets:
+                        self.bucket_hits += 1
+                    else:
+                        self._buckets.add((bb, sb))
+                    self.real_tokens += real
+                    self.padded_tokens += bb * sb - real
+                with spans.span("dispatch"):   # a retrace or compile too
+                    out = self._embed(self.params, toks, mask)
+                handles.append((len(chunk), out))
         return handles
 
-    def embed_batch(self, queries: Sequence[Query]) -> List[np.ndarray]:
-        out: List[np.ndarray] = []
-        for n, dev in self._enqueue_chunks(queries):
-            emb = np.asarray(dev)
-            out.extend(emb[i] for i in range(n))
+    @staticmethod
+    def _fetch(handles, spans=NO_SPANS, batch: int | None = None
+               ) -> List[np.ndarray]:
+        """The batch's embeddings from its chunks' device results: the
+        tier's ``fetch`` span, split into ``ready`` (the host waits for the
+        results: the executions and their device-to-host transfers) and
+        ``copy`` (the per-row split on the host).  The wait and the
+        transfer share one synchronisation: a wait of its own before the
+        transfer costs the device a round trip through the host per
+        batch."""
+        with spans.span("fetch", batch):
+            with spans.span("ready"):
+                # blocks until ready; gathers a sharded result
+                arrs = [(n, np.asarray(dev)) for n, dev in handles]
+            with spans.span("copy"):
+                out: List[np.ndarray] = []
+                for n, arr in arrs:
+                    out.extend(arr[i] for i in range(n))
         return out
+
+    def embed_batch(self, queries: Sequence[Query]) -> List[np.ndarray]:
+        spans = self._spans()
+        return self._fetch(self._enqueue_chunks(queries), spans, spans.batch)

@@ -45,7 +45,7 @@ import numpy as np
 from repro.core.bucketing import BucketedEmbedderBackend, default_buckets, \
     next_pow2
 from repro.core.routing import Query
-from repro.core.telemetry import Telemetry
+from repro.core.telemetry import NO_SPANS, Telemetry
 
 
 _cpu_donation_warning_filtered = False
@@ -245,14 +245,16 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
                                self.max_tokens, self.min_seq_bucket,
                                self.min_batch_bucket)
 
-    def _stage_chunk(self, chunk: Sequence[Query], bb: int, sb: int):
+    def _stage_chunk(self, chunk: Sequence[Query], bb: int, sb: int,
+                     spans=NO_SPANS):
         """Tokenize into the (bb, sb) bucket's next staging slot and ship it
         to the mesh.  The slot rotates through the ring so a buffer is only
         refilled ``staging_slots`` batches later — by which point the
         double-buffered worker has fetched (hence the device has consumed)
         the execution that read it.  The lock covers slot pick + fill +
         transfer, so worker threads can share one backend (raise
-        ``staging_slots`` beyond 2 workers)."""
+        ``staging_slots`` beyond 2 workers).  ``spans`` times the
+        ``tokenize`` and ``device_put`` (both transfers) phases."""
         key = (bb, sb)
         with self._staging_lock:
             pending = self._staging_pending.get(key, 0)
@@ -275,10 +277,12 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
                     ring.append((np.zeros((bb, sb), np.int32),
                                  np.zeros((bb, sb), np.float32)))
                 out = ring[use % len(ring)]
-                toks, mask, real, truncated = self._tokenize(chunk, sb,
-                                                             out=out)
-                td = self._jax.device_put(toks, self._batch_sharding)
-                md = self._jax.device_put(mask, self._batch_sharding)
+                with spans.span("tokenize"):
+                    toks, mask, real, truncated = self._tokenize(chunk, sb,
+                                                                 out=out)
+                with spans.span("device_put"):
+                    td = self._jax.device_put(toks, self._batch_sharding)
+                    md = self._jax.device_put(mask, self._batch_sharding)
             except Exception:
                 # failed BEFORE the caller could capture the key for its
                 # own rollback: undo the pending count here or the bucket
@@ -313,7 +317,11 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
         ._enqueue_chunks``).  The fetch thunk performs the blocking
         device->host copy — the engine worker calls it one batch late
         (double buffering) so the copy overlaps the next batch's compute.
+        Staging is the tier's ``stage`` span and the thunk its ``fetch``
+        span, both carrying the batch number the worker gave this batch.
         """
+        spans = self._spans()
+        batch = spans.batch
         self._staging_tl.keys = []
         try:
             handles = self._enqueue_chunks(queries)
@@ -328,15 +336,11 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
 
         def fetch() -> List[np.ndarray]:
             try:
-                out: List[np.ndarray] = []
-                for n, dev in handles:
-                    arr = np.asarray(dev)  # blocks until ready; gathers
-                    out.extend(arr[i] for i in range(n))
+                return self._fetch(handles, spans, batch)
             finally:
                 # results copied out: the executions consumed their staged
                 # inputs, so the slots may rotate again
                 self._release_staging(keys)
-            return out
 
         return fetch
 

@@ -11,17 +11,121 @@ same underlying counts.
 
 ``DispatchStats``/``EngineStats``/``SimResult`` remain as aliases so older
 call sites keep importing their familiar name.
+
+``HostSpans`` times the threaded engine's host phases: each phase is a
+``jax.profiler.TraceAnnotation`` span (on the device trace's clock while a
+profiler session is active, a few microseconds otherwise) and a count and
+total of seconds that ``Telemetry.summary()`` reports per batch.  The DES
+has no host phases and never creates one.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
+import time
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Dict, List, Optional
+from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 import numpy as np
 
 if TYPE_CHECKING:  # pragma: no cover - avoid circular import at runtime
     from repro.core.routing import Query
+
+# host phases of one tier's batches, in the order a worker meets them, then
+# the caller-side submit.  ``wait``/``pop``/``stage``/``fetch``/``complete``/
+# ``hooks`` tile the worker's loop; ``tokenize``/``device_put``/``dispatch``
+# nest in ``stage`` (once per chunk), ``ready``/``copy`` in ``fetch``.
+PHASES = ("wait", "pop", "stage", "tokenize", "device_put", "dispatch",
+          "fetch", "ready", "copy", "complete", "hooks", "submit")
+
+
+class _Span:
+    """One timed phase: a profiler span plus a count and seconds."""
+
+    __slots__ = ("_owner", "_phase", "_ann", "_t0")
+
+    def __init__(self, owner: "HostSpans", phase: str, ann) -> None:
+        self._owner, self._phase, self._ann = owner, phase, ann
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self._owner._add(self._phase, dt)
+
+
+class HostSpans:
+    """The spans ``windve.<tier>.<phase>`` of one tier (``windve.submit``
+    for ``tier=""``), and each phase's count and seconds.
+
+    Names are built once, here; ``span(phase)`` formats nothing.  The
+    counters take this object's own lock, never the ``Telemetry`` lock that
+    ``submit`` and ``record_completion`` share, and hold two fixed dicts,
+    so a long-running engine keeps no growing state.  ``next_batch`` hands
+    the calling worker thread the tier's next batch number; spans given
+    ``batch`` carry it as profiler metadata (``batch=<n>``) while a
+    profiler session is active, which links an async ``stage(N)`` and the
+    ``fetch(N-1)`` beside it to their own batches.
+    """
+
+    def __init__(self, tier: str = ""):
+        from jax.profiler import TraceAnnotation
+
+        self._ann = TraceAnnotation
+        prefix = f"windve.{tier.lower()}." if tier else "windve."
+        self.names = {p: prefix + p for p in PHASES}
+        self._count = dict.fromkeys(PHASES, 0)
+        self._secs = dict.fromkeys(PHASES, 0.0)
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self.batches = 0
+
+    def span(self, phase: str, batch: Optional[int] = None) -> _Span:
+        ann = self._ann
+        if batch is not None and ann.is_enabled():
+            return _Span(self, phase, ann(self.names[phase], batch=batch))
+        return _Span(self, phase, ann(self.names[phase]))
+
+    def next_batch(self) -> int:
+        """Number the batch the calling worker thread just popped."""
+        with self._lock:
+            self.batches += 1
+            n = self.batches
+        self._local.batch = n
+        return n
+
+    @property
+    def batch(self) -> Optional[int]:
+        """The calling thread's current batch number (None before one)."""
+        return getattr(self._local, "batch", None)
+
+    def _add(self, phase: str, dt: float) -> None:
+        with self._lock:
+            self._count[phase] += 1
+            self._secs[phase] += dt
+
+    def totals(self) -> Dict[str, Tuple[int, float]]:
+        """``{phase: (count, seconds)}`` of every phase timed so far."""
+        with self._lock:
+            return {p: (n, self._secs[p])
+                    for p, n in self._count.items() if n}
+
+
+class NoSpans:
+    """What a backend times with before an engine wires its tier."""
+
+    batch = None
+    _none = contextlib.nullcontext()
+
+    def span(self, phase: str, batch: Optional[int] = None):
+        return self._none
+
+
+NO_SPANS = NoSpans()
 
 
 @dataclass
@@ -76,6 +180,39 @@ class Telemetry:
     tier_batch_latencies: Dict[str, List[float]] = field(default_factory=dict)
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False, compare=False)
+    # the threaded engine's host phases, keyed by tier ("" for submit);
+    # empty for the DES, so nothing the parity suites compare carries them
+    _host_spans: Dict[str, HostSpans] = field(default_factory=dict,
+                                              repr=False, compare=False)
+
+    def spans(self, tier: str = "") -> HostSpans:
+        """The tier's :class:`HostSpans` (``""``: the submit span), made on
+        first use; the engine asks once per worker, a backend once per
+        batch."""
+        s = self._host_spans.get(tier)
+        if s is None:
+            new = HostSpans(tier)
+            with self._lock:
+                s = self._host_spans.setdefault(tier, new)
+        return s
+
+    def host_ms_per_batch(self) -> Dict[str, Dict[str, float]]:
+        """``{tier: {phase: ms}}``: each phase's host time over the batches
+        the tier's workers popped (``wait`` and empty pops included)."""
+        out: Dict[str, Dict[str, float]] = {}
+        with self._lock:
+            tiers = sorted(self._host_spans.items())
+        for tier, s in tiers:
+            if tier and s.batches:
+                out[tier] = {p: 1e3 * secs / s.batches
+                             for p, (_, secs) in s.totals().items()}
+        return out
+
+    def host_us_per_submit(self) -> Optional[float]:
+        """Mean host time of one ``submit`` call, in microseconds."""
+        s = self._host_spans.get("")
+        n, secs = s.totals().get("submit", (0, 0.0)) if s else (0, 0.0)
+        return 1e6 * secs / n if n else None
 
     # -- writers (QueueManager.dispatch / the drivers) ---------------------
     def record_dispatch(self, tier: str) -> None:
@@ -307,7 +444,9 @@ class Telemetry:
         per-reason ``rejections_*`` and per-stage ``brownout_to_*`` keys
         join the record only when a rejection or brownout transition
         actually happened.  ``clean_shutdown`` appears once the engine has shut down:
-        1.0 when every worker thread joined, 0.0 when one leaked."""
+        1.0 when every worker thread joined, 0.0 when one leaked.  The
+        threaded engine's host phases join as ``host_ms_<phase>_<tier>``
+        (milliseconds per batch) and ``host_us_submit`` once timed."""
         fault: Dict[str, float] = {}
         if (self.deadline_misses or self.retries or self.backend_errors
                 or self.breaker_trips or self.breaker_recoveries
@@ -347,10 +486,18 @@ class Telemetry:
                    for k in sorted(set(self.cache_hits)
                                    | set(self.cache_misses))},
             }
+        host: Dict[str, float] = {
+            f"host_ms_{p}_{tier}": ms
+            for tier, phases in self.host_ms_per_batch().items()
+            for p, ms in phases.items()}
+        submit_us = self.host_us_per_submit()
+        if submit_us is not None:
+            host["host_us_submit"] = submit_us
         return {
             **fault,
             **overload,
             **cache,
+            **host,
             "accepted": self.accepted,
             "rejected": self.rejected,
             "completed": self.n_completed,

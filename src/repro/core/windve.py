@@ -23,7 +23,9 @@ Backends:
 Observability: ``add_batch_hook(fn)`` registers a first-class batch
 completion hook ``fn(tier_name, batch, service_latency_s)`` — the online
 calibrator (``repro.core.adaptive``) attaches through this instead of
-monkey-patching ``embed_batch``.
+monkey-patching ``embed_batch``.  Each worker's host phases are spans
+``windve.<tier>.<phase>`` on the profiler's clock, counted per tier in the
+shared ``Telemetry`` (``repro.core.telemetry.HostSpans``).
 """
 from __future__ import annotations
 
@@ -41,7 +43,7 @@ from repro.core.routing import (ADMISSION, BUSY, CPU, EXPIRED, NPU,
                                 QueueManager, RetryPolicy, ServeError,
                                 TierSpec)
 from repro.core.simulator import DeviceModel, sharded_model
-from repro.core.telemetry import EngineStats, Telemetry
+from repro.core.telemetry import NO_SPANS, EngineStats, Telemetry
 
 BatchHook = Callable[[str, Sequence[Query], float], None]
 
@@ -51,11 +53,15 @@ class Backend:
 
     ``telemetry`` (optional): a :class:`~repro.core.telemetry.Telemetry` the
     backend reports quality events (payload truncations) into.  ``WindVE``
-    wires its shared stats object into any backend that left it None.
+    wires its shared stats object into any backend that left it None, and
+    the name of the tier it serves into ``tier`` the same way; a backend
+    with both times its host phases as that tier's spans
+    (``Telemetry.spans``).
     """
 
     name = "backend"
     telemetry: Optional[Telemetry] = None
+    tier: Optional[str] = None
     # backends that can enqueue a batch and hand back a deferred fetch set
     # this True and implement ``embed_batch_async`` (see
     # ``repro.core.sharded_backend``); the engine worker then double-buffers.
@@ -69,6 +75,13 @@ class Backend:
         """Enqueue the batch; the returned thunk blocks for the results."""
         out = self.embed_batch(queries)
         return lambda: out
+
+    def _spans(self):
+        """The serving tier's host spans; no-ops before an engine wires
+        the tier and its telemetry."""
+        if self.tier is None or self.telemetry is None:
+            return NO_SPANS
+        return self.telemetry.spans(self.tier)
 
 
 class ModeledBackend(Backend):
@@ -312,11 +325,15 @@ class WindVE:
         self.stats: EngineStats = self.qm.stats   # one shared Telemetry
         self.backends: Dict[str, Backend] = {t.name: t.backend
                                              for t in device_tiers}
-        for be in self.backends.values():
+        for name, be in self.backends.items():
             # backends report quality events (truncations) into the engine's
-            # shared telemetry unless the caller wired their own
+            # shared telemetry unless the caller wired their own, and time
+            # their host phases as the tier they serve
             if getattr(be, "telemetry", False) is None:
                 be.telemetry = self.stats
+            if getattr(be, "tier", False) is None:
+                be.tier = name
+        self._submit_spans = self.stats.spans()
         self._batch_hooks: List[BatchHook] = []
         self._futures: Dict[int, Future] = {}
         self._qid = 0
@@ -387,40 +404,44 @@ class WindVE:
         """
         if deadline_s is None:
             deadline_s = self.default_deadline_s
-        with self._lock:
-            self._qid += 1
-            now = time.monotonic()
-            q = Query(qid=self._qid, payload=payload, length=length,
-                      arrival_t=now,
-                      deadline=None if deadline_s is None
-                      else now + deadline_s)
-        fut: Future = Future()
-        self._futures[q.qid] = fut
-        verdict = self.qm.dispatch(q)
-        if verdict == BUSY:
-            self._futures.pop(q.qid, None)
-            return None
-        if verdict == EXPIRED:
-            self._fail(q, DeadlineExceeded(qid=q.qid, attempts=q.attempts))
+        with self._submit_spans.span("submit"):
+            with self._lock:
+                self._qid += 1
+                now = time.monotonic()
+                q = Query(qid=self._qid, payload=payload, length=length,
+                          arrival_t=now,
+                          deadline=None if deadline_s is None
+                          else now + deadline_s)
+            fut: Future = Future()
+            self._futures[q.qid] = fut
+            verdict = self.qm.dispatch(q)
+            if verdict == BUSY:
+                self._futures.pop(q.qid, None)
+                return None
+            if verdict == EXPIRED:
+                self._fail(q, DeadlineExceeded(qid=q.qid,
+                                               attempts=q.attempts))
+                return fut
+            if verdict == ADMISSION:
+                # admission shed at arrival is a REJECTION
+                # (rejections_admission counts it), not a terminal serving
+                # failure — the future carries the structured error but
+                # `failed` stays untouched, mirroring how BUSY rejections
+                # never count as failed
+                self._futures.pop(q.qid, None)
+                fut.set_exception(ServeError("admission", qid=q.qid))
+                return fut
+            if self.qm.is_cache_tier(verdict):
+                # zero-latency tier: the hit already filled q.emb at
+                # dispatch — complete here, no queue slot, no worker, no
+                # batch
+                q.done_t = time.monotonic()
+                self.stats.record_completion(q, verdict)
+                self._futures.pop(q.qid, None)
+                fut.set_result(q.emb)
+                return fut
+            self._wake[verdict].set()
             return fut
-        if verdict == ADMISSION:
-            # admission shed at arrival is a REJECTION (rejections_admission
-            # counts it), not a terminal serving failure — the future
-            # carries the structured error but `failed` stays untouched,
-            # mirroring how BUSY rejections never count as failed
-            self._futures.pop(q.qid, None)
-            fut.set_exception(ServeError("admission", qid=q.qid))
-            return fut
-        if self.qm.is_cache_tier(verdict):
-            # zero-latency tier: the hit already filled q.emb at dispatch —
-            # complete here, no queue slot, no worker, no batch
-            q.done_t = time.monotonic()
-            self.stats.record_completion(q, verdict)
-            self._futures.pop(q.qid, None)
-            fut.set_result(q.emb)
-            return fut
-        self._wake[verdict].set()
-        return fut
 
     def add_batch_hook(self, hook: BatchHook) -> BatchHook:
         """Register ``hook(tier_name, batch, service_latency_s)``, called by
@@ -538,10 +559,14 @@ class WindVE:
         # deferred until the NEXT batch is enqueued, so device->host copy of
         # batch N-1 overlaps batch N's compute and the worker never idles on
         # ``device_get``.
-        pending = None   # (batch, fetch_thunk, t0)
+        pending = None   # (batch, fetch_thunk, t0, batch number)
+        # the tier's host spans (``windve.<tier>.<phase>``): wait, pop,
+        # complete and hooks here, stage and fetch in the backend; together
+        # they tile this thread's time
+        spans = self.stats.spans(tier_name)
 
         def resolve(entry) -> None:
-            batch, fetch, t0 = entry
+            batch, fetch, t0, seq = entry
             try:
                 embs = fetch()
                 err: Optional[BaseException] = None
@@ -559,25 +584,28 @@ class WindVE:
                 if not isinstance(err, Exception):
                     raise err           # genuine worker death (accounted)
                 return
-            self.qm.tier_success(tier_name, service, now)
-            self.stats.record_batch(tier_name, service)
-            admit = bool(self.qm.cache_tiers)
-            for q, emb in zip(batch, embs):
-                q.done_t = now
-                self.stats.record_completion(q, tier_name)
-                if admit:
-                    # admission hook: insert BEFORE the future resolves, so
-                    # a client that saw this result re-submitting the same
-                    # tokens is guaranteed the cache hit
-                    self.qm.admit(q, emb)
-                fut = self._futures.pop(q.qid, None)
-                if fut is not None:
-                    fut.set_result(emb)
-            for hook in list(self._batch_hooks):
-                try:
-                    hook(tier_name, batch, service)
-                except Exception:      # hooks must not kill the worker
-                    self.stats.record_hook_error()
+            with spans.span("complete", seq):
+                self.qm.tier_success(tier_name, service, now)
+                self.stats.record_batch(tier_name, service)
+                admit = bool(self.qm.cache_tiers)
+                for q, emb in zip(batch, embs):
+                    q.done_t = now
+                    self.stats.record_completion(q, tier_name)
+                    if admit:
+                        # admission hook: insert BEFORE the future
+                        # resolves, so a client that saw this result
+                        # re-submitting the same tokens is guaranteed the
+                        # cache hit
+                        self.qm.admit(q, emb)
+                    fut = self._futures.pop(q.qid, None)
+                    if fut is not None:
+                        fut.set_result(emb)   # runs clients' callbacks
+            with spans.span("hooks", seq):
+                for hook in list(self._batch_hooks):
+                    try:
+                        hook(tier_name, batch, service)
+                    except Exception:      # hooks must not kill the worker
+                        self.stats.record_hook_error()
 
         crash: Optional[BaseException] = None
         try:
@@ -585,28 +613,36 @@ class WindVE:
                 # live values: online re-calibration may resize the depth;
                 # qm.pop_batch honours the tier's bucket_fn (length-aware
                 # batches) and sweeps deadline-dead work out first
-                batch = self.qm.pop_batch(tier_name, now=time.monotonic())
+                with spans.span("pop"):
+                    batch = self.qm.pop_batch(tier_name,
+                                              now=time.monotonic())
+                    if batch:
+                        t0 = time.monotonic()
+                        for q in batch:
+                            q.start_t = t0
+                        seq = spans.next_batch()
                 if not batch:
                     if pending is not None:  # drain: nothing left to overlap
                         entry, pending = pending, None
                         resolve(entry)
                         continue
-                    self._wake[tier_name].wait(timeout=0.01)
+                    with spans.span("wait"):
+                        self._wake[tier_name].wait(timeout=0.01)
                     self._wake[tier_name].clear()
                     continue
-                t0 = time.monotonic()
                 if use_async:
                     try:
                         fetch = backend.embed_batch_async(batch)
                     except Exception as e:
                         def fetch(err=e):
                             raise err
-                    prev, pending = pending, (batch, fetch, t0)
+                    prev, pending = pending, (batch, fetch, t0, seq)
                     if prev is not None:
                         resolve(prev)
                 else:
                     resolve((batch,
-                             (lambda b=batch: backend.embed_batch(b)), t0))
+                             (lambda b=batch: backend.embed_batch(b)), t0,
+                             seq))
             if pending is not None:  # pragma: no cover - shutdown mid-flight
                 entry, pending = pending, None
                 resolve(entry)

@@ -54,6 +54,7 @@ from repro.core.routing import (CPU, NPU, CascadePolicy, LeastLoadedPolicy,
                                 replicate)
 from repro.core.sharded_backend import ShardedEmbedderBackend, _serve_devices
 from repro.core.simulator import PAPER_DEVICES, profile_fn_for
+from repro.core.telemetry import PHASES
 from repro.core.windve import ModeledBackend, WindVE
 from repro.data.workload import make_queries
 from repro.launch.compile_cache import enable_compile_cache
@@ -419,6 +420,14 @@ def report(engine, n_queries: int, wall: float, completed: int,
     print(f"[serve] batch service tail: p50={s.batch_p(50)*1e3:.1f}ms "
           f"p95={s.batch_p(95)*1e3:.1f}ms p99={s.batch_p(99)*1e3:.1f}ms "
           f"over {len(s.batch_latencies)} batches  [{tails}]")
+    # host time per batch by phase (``windve.<tier>.<phase>`` spans): what
+    # the worker did besides waiting on the device
+    for tier, phases in s.host_ms_per_batch().items():
+        print(f"[serve] host ms/batch {tier}: " + " ".join(
+            f"{p}={phases[p]:.3f}" for p in PHASES if p in phases))
+    submit_us = s.host_us_per_submit()
+    if submit_us is not None:
+        print(f"[serve] host submit: {submit_us:.1f}us/query")
     traces = {name: be.traces for name, be in engine.backends.items()
               if hasattr(be, "traces")}
     if traces:
