@@ -22,11 +22,13 @@ This module spends that stability on the device side of the batch:
   instead of allocating fresh HBM per batch; paired with one reusable host
   staging array pair per (B, S) bucket, steady-state serving performs zero
   fresh host allocations and zero retraces.
-* **async dispatch** — ``embed_batch_async`` returns as soon as every chunk
-  execution is enqueued; the returned fetch thunk blocks for device->host
-  transfer.  The engine worker (``repro.core.windve``) double-buffers: batch
-  N-1's fetch overlaps batch N's compute, so the worker thread stops
-  blocking on ``device_get``.
+* **pipelined drain** — ``embed_batch_async`` returns as soon as every
+  chunk execution is enqueued; the returned fetch thunk blocks for the
+  device->host transfer.  On an accelerator mesh the engine worker
+  (``repro.core.windve``) pipelines its drain: it stages and enqueues batch
+  N+1 before it fetches batch N, so the host's staging, completion and hooks
+  run while the device computes.  A CPU mesh drains synchronously: its
+  XLA:CPU step runs on the cores the worker needs, so overlap buys nothing.
 
 Correctness notes: the batch bucket floor is raised to the mesh's
 data-parallel size so every chunk's batch dim divides the mesh exactly (jit
@@ -116,11 +118,13 @@ def embed_step(cfg, mesh, compute_dtype, act_quant: bool, *,
 class ShardedEmbedderBackend(BucketedEmbedderBackend):
     """Bucketed embedder fanned out over a data-parallel device mesh.
 
-    ``dtype`` / ``donate`` / ``async_dispatch`` default to the §Perf flags
-    (``embed_dtype`` / ``embed_donate`` / ``embed_async``), so a
-    default-constructed backend is the paper-faithful fp32 synchronous
-    baseline and every optimization is a reproducible baseline-vs-change
-    row.  ``dtype`` policies (``repro.models.quantize.serve_params``):
+    ``dtype`` / ``donate`` default to the §Perf flags (``embed_dtype`` /
+    ``embed_donate``), so a default-constructed backend is the
+    paper-faithful fp32 baseline and every optimization is a reproducible
+    baseline-vs-change row.  ``async_dispatch`` (whether the engine worker
+    pipelines this tier's drain) defaults to the mesh's platform: on for an
+    accelerator, off for the host CPU; pass it to pin either path.
+    ``dtype`` policies (``repro.models.quantize.serve_params``):
     ``fp32`` oracle, ``bf16`` resident weights, ``int8`` weight-only
     quantized projections (int8 weights + fp32 dequant scales, fp32
     activations, the fused quant matmul in the trunk; served vectors stay
@@ -154,14 +158,17 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
         # (validates dtype and raises a ValueError listing the policies)
         served, cdt = serve_params(params, dtype)
         donate = flags.embed_donate if donate is None else bool(donate)
-        self.async_dispatch = (flags.embed_async if async_dispatch is None
-                               else bool(async_dispatch))
         if mesh is None:
             mesh = make_serve_mesh(_serve_devices(devices))
         self.mesh = mesh
         # kernels follow this platform: compiled on "tpu", jnp references
         # on "cpu" (see ``embed_step``)
         self.platform = mesh.devices.flat[0].platform
+        # an accelerator's executions leave the host's cores free, so its
+        # worker stages the next batch while the device computes; on the
+        # CPU the step itself needs those cores
+        self.async_dispatch = (self.platform != "cpu" if async_dispatch is None
+                               else bool(async_dispatch))
         ndev = 1
         for a in dp_axes(mesh):
             ndev *= mesh.shape[a]
@@ -213,8 +220,8 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
         # (B, S) bucket.  ``device_put`` may defer (or, for large aligned
         # arrays, zero-copy alias) the host buffer, so a slot must not be
         # refilled while an enqueued execution can still read it.  The
-        # default depth covers the worker's double-buffering discipline (at
-        # most 2 undelivered batches per worker) for up to 2 workers;
+        # default depth covers the worker's pipelined drain (at most 2
+        # undelivered batches per worker) for up to 2 workers;
         # callers sharing one backend across more workers, or holding more
         # fetches back, must raise ``staging_slots`` to 2 x workers.
         # Steady-state host allocation stays bounded at ``staging_slots``
@@ -250,7 +257,7 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
         """Tokenize into the (bb, sb) bucket's next staging slot and ship it
         to the mesh.  The slot rotates through the ring so a buffer is only
         refilled ``staging_slots`` batches later — by which point the
-        double-buffered worker has fetched (hence the device has consumed)
+        pipelined worker has fetched (hence the device has consumed)
         the execution that read it.  The lock covers slot pick + fill +
         transfer, so worker threads can share one backend (raise
         ``staging_slots`` beyond 2 workers).  ``spans`` times the
@@ -266,7 +273,7 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
                     f"host buffers an enqueued execution may still read "
                     f"(rotated embeddings).  More than 2 worker threads — "
                     f"or callers holding fetches back beyond the worker's "
-                    f"double-buffering — share this backend: construct it "
+                    f"pipelined drain — share this backend: construct it "
                     f"with staging_slots >= 2 x workers.")
             self._staging_pending[key] = pending + 1
             try:
@@ -315,8 +322,9 @@ class ShardedEmbedderBackend(BucketedEmbedderBackend):
         enqueued, so this method costs staging + dispatch only (the shared
         chunking/accounting path in ``BucketedEmbedderBackend
         ._enqueue_chunks``).  The fetch thunk performs the blocking
-        device->host copy — the engine worker calls it one batch late
-        (double buffering) so the copy overlaps the next batch's compute.
+        device->host copy — a pipelining engine worker calls it after it
+        has enqueued the next batch, so the device never waits on the host
+        between the two.
         Staging is the tier's ``stage`` span and the thunk its ``fetch``
         span, both carrying the batch number the worker gave this batch.
         """

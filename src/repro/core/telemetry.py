@@ -155,6 +155,9 @@ class Telemetry:
     backend_errors: Dict[str, int] = field(default_factory=dict)
     breaker_trips: Dict[str, int] = field(default_factory=dict)
     breaker_recoveries: Dict[str, int] = field(default_factory=dict)
+    # the threaded engine's pipelined drain: per tier, batches enqueued
+    # while the same worker still had a batch in flight
+    overlapped_batches: Dict[str, int] = field(default_factory=dict)
     failed: int = 0              # queries whose futures terminally failed
     hook_errors: int = 0         # batch hooks that raised (and were caught)
     # overload-control counters: rejections broken down by reason
@@ -247,8 +250,16 @@ class Telemetry:
             with self._lock:
                 self.truncated += n
 
+    def record_overlapped_batch(self, tier: str) -> None:
+        """The engine enqueued a batch of ``tier`` while the same worker
+        still had its previous batch in flight (the pipelined drain)."""
+        with self._lock:
+            self.overlapped_batches[tier] = \
+                self.overlapped_batches.get(tier, 0) + 1
+
     def record_batch(self, tier: str, service_s: float) -> None:
-        """One batch execution's service latency (enqueue -> results ready).
+        """One batch execution's service latency (its pop, or the previous
+        batch's results on a pipelined drain, -> its results ready).
         Both drivers report it, so tail service latency (``batch_p``) is a
         first-class metric next to per-query e2e latency — means hide the
         p99 stalls that actually break the SLO contract.  Kept per tier as
@@ -446,7 +457,9 @@ class Telemetry:
         actually happened.  ``clean_shutdown`` appears once the engine has shut down:
         1.0 when every worker thread joined, 0.0 when one leaked.  The
         threaded engine's host phases join as ``host_ms_<phase>_<tier>``
-        (milliseconds per batch) and ``host_us_submit`` once timed."""
+        (milliseconds per batch) and ``host_us_submit`` once timed, each
+        tier's popped ``batches_<tier>`` with ``overlapped_batches_<tier>``,
+        those enqueued while the worker had a batch in flight."""
         fault: Dict[str, float] = {}
         if (self.deadline_misses or self.retries or self.backend_errors
                 or self.breaker_trips or self.breaker_recoveries
@@ -486,10 +499,15 @@ class Telemetry:
                    for k in sorted(set(self.cache_hits)
                                    | set(self.cache_misses))},
             }
+        per_batch = self.host_ms_per_batch()
         host: Dict[str, float] = {
             f"host_ms_{p}_{tier}": ms
-            for tier, phases in self.host_ms_per_batch().items()
+            for tier, phases in per_batch.items()
             for p, ms in phases.items()}
+        for tier in per_batch:
+            host[f"batches_{tier}"] = self._host_spans[tier].batches
+            host[f"overlapped_batches_{tier}"] = \
+                self.overlapped_batches.get(tier, 0)
         submit_us = self.host_us_per_submit()
         if submit_us is not None:
             host["host_us_submit"] = submit_us

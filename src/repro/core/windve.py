@@ -48,6 +48,13 @@ from repro.core.telemetry import NO_SPANS, EngineStats, Telemetry
 BatchHook = Callable[[str, Sequence[Query], float], None]
 
 
+def pipelines(backend) -> bool:
+    """Whether a tier on ``backend`` drains pipelined: batch N+1 enqueued
+    (``embed_batch_async``) before batch N's results are fetched."""
+    return bool(getattr(backend, "async_dispatch", False)) and \
+        callable(getattr(backend, "embed_batch_async", None))
+
+
 class Backend:
     """A device pool able to embed a batch of queries.
 
@@ -64,7 +71,8 @@ class Backend:
     tier: Optional[str] = None
     # backends that can enqueue a batch and hand back a deferred fetch set
     # this True and implement ``embed_batch_async`` (see
-    # ``repro.core.sharded_backend``); the engine worker then double-buffers.
+    # ``repro.core.sharded_backend``); the engine worker then pipelines its
+    # drain, enqueueing batch N+1 before it fetches batch N.
     async_dispatch = False
 
     def embed_batch(self, queries: Sequence[Query]) -> List[np.ndarray]:
@@ -445,7 +453,10 @@ class WindVE:
 
     def add_batch_hook(self, hook: BatchHook) -> BatchHook:
         """Register ``hook(tier_name, batch, service_latency_s)``, called by
-        the worker after every completed batch (calibration, metrics, ...)."""
+        the worker after every completed batch (calibration, metrics, ...).
+        ``service_latency_s`` is that batch's own service: from its pop (or,
+        on a pipelined drain, from the previous batch's results reaching
+        the host, if later) to its results on the host."""
         self._batch_hooks.append(hook)
         return hook
 
@@ -553,19 +564,22 @@ class WindVE:
     def _worker(self, tier_name: str) -> None:
         backend = self.backends[tier_name]
         queue = self.qm.queues[tier_name]
-        use_async = bool(getattr(backend, "async_dispatch", False)) and \
-            callable(getattr(backend, "embed_batch_async", None))
-        # double buffering (async backends): the previous batch's fetch is
-        # deferred until the NEXT batch is enqueued, so device->host copy of
-        # batch N-1 overlaps batch N's compute and the worker never idles on
-        # ``device_get``.
+        pipelined = pipelines(backend)
+        # the pipelined drain (async backends): batch N+1 is popped, staged
+        # and enqueued before batch N is fetched, so the host's work between
+        # two batches (completion, hooks, the next pop and staging) runs
+        # while the device computes.  At most two of this worker's batches
+        # are in flight.
         pending = None   # (batch, fetch_thunk, t0, batch number)
+        # when the previous batch's results reached the host
+        last_ready = float("-inf")
         # the tier's host spans (``windve.<tier>.<phase>``): wait, pop,
         # complete and hooks here, stage and fetch in the backend; together
         # they tile this thread's time
         spans = self.stats.spans(tier_name)
 
         def resolve(entry) -> None:
+            nonlocal last_ready
             batch, fetch, t0, seq = entry
             try:
                 embs = fetch()
@@ -575,7 +589,15 @@ class WindVE:
                 # (SystemExit and friends) must not strand this batch's
                 # futures — account for it, THEN let it propagate
                 embs, err = None, e
-            service = time.monotonic() - t0
+            ready = time.monotonic()
+            # one batch's service: from its pop, or from the moment the
+            # batch before it came back if that was later (the pipelined
+            # drain: it waited behind that batch on the device), to its own
+            # results on the host.  The synchronous drain always has the
+            # previous batch back before it pops, so there it is pop to
+            # results.
+            service = ready - max(t0, last_ready)
+            last_ready = ready
             now = time.monotonic()
             queue.finish(len(batch))   # slots free before any re-dispatch
             if err is not None:
@@ -630,12 +652,15 @@ class WindVE:
                         self._wake[tier_name].wait(timeout=0.01)
                     self._wake[tier_name].clear()
                     continue
-                if use_async:
+                if pipelined:
                     try:
                         fetch = backend.embed_batch_async(batch)
                     except Exception as e:
                         def fetch(err=e):
                             raise err
+                    else:
+                        if pending is not None:
+                            self.stats.record_overlapped_batch(tier_name)
                     prev, pending = pending, (batch, fetch, t0, seq)
                     if prev is not None:
                         resolve(prev)
@@ -649,7 +674,7 @@ class WindVE:
         except BaseException as e:   # worker death, not a batch failure
             crash = e
             if pending is not None:
-                # a double-buffered batch this worker still owned: account
+                # a pipelined batch this worker still owned: account
                 # it (resolve never saw it, so no double-finish risk)
                 b, pending = pending[0], None
                 queue.finish(len(b))

@@ -7,7 +7,7 @@ queue-depth calibration (an Eq. 12 sweep of each tier's own backend) ->
 queue manager -> threaded engine -> workload replay -> stats::
 
     PYTHONPATH=src python -m repro.launch.serve --queries 64 --slo 1.0 \
-        --opt embed_dtype=bf16,embed_donate=1,embed_async=1 --prewarm
+        --opt embed_dtype=bf16,embed_donate=1 --prewarm
 
 The model serves at its published width; ``--smoke`` swaps in the narrow
 ``.smoke()`` config for a CPU rehearsal (``JAX_PLATFORMS=cpu``), where
@@ -55,7 +55,7 @@ from repro.core.routing import (CPU, NPU, CascadePolicy, LeastLoadedPolicy,
 from repro.core.sharded_backend import ShardedEmbedderBackend, _serve_devices
 from repro.core.simulator import PAPER_DEVICES, profile_fn_for
 from repro.core.telemetry import PHASES
-from repro.core.windve import ModeledBackend, WindVE
+from repro.core.windve import ModeledBackend, WindVE, pipelines
 from repro.data.workload import make_queries
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import replica_groups
@@ -93,9 +93,25 @@ def profile_fn(backend, vocab: int, max_batch: int, seed: int = 0
                ) -> Callable[..., float]:
     """Eq. 12 probe of a real backend: the seconds it takes to serve ``c``
     queued queries of ``length`` tokens in batches of at most
-    ``max_batch``, the way the engine's worker drains its queue.  Every
-    shape is run once before the clock starts, so a probe times service
-    and never compilation; the best of two timed passes is returned."""
+    ``max_batch``, the way the engine's worker drains its queue: on a
+    pipelined backend (``windve.pipelines``) chunk k+1 is enqueued before
+    chunk k is fetched, on any other chunk by chunk.  Every shape is run
+    once before the clock starts, so a probe times service and never
+    compilation; the best of two timed passes is returned."""
+    pipelined = pipelines(backend)
+
+    def drain(chunks) -> None:
+        if not pipelined:
+            for chunk in chunks:
+                backend.embed_batch(chunk)
+            return
+        fetch = None
+        for chunk in chunks:
+            nxt = backend.embed_batch_async(chunk)
+            if fetch is not None:
+                fetch()
+            fetch = nxt
+        fetch()
 
     def profile(c: int, length: int = QUERY_LENGTH) -> float:
         qs = [Query(qid=i, payload=p, length=length) for i, p in
@@ -106,8 +122,7 @@ def profile_fn(backend, vocab: int, max_batch: int, seed: int = 0
         best = float("inf")
         for _ in range(2):
             t0 = time.monotonic()
-            for chunk in chunks:
-                backend.embed_batch(chunk)
+            drain(chunks)
             best = min(best, time.monotonic() - t0)
         return best
 
@@ -421,10 +436,13 @@ def report(engine, n_queries: int, wall: float, completed: int,
           f"p95={s.batch_p(95)*1e3:.1f}ms p99={s.batch_p(99)*1e3:.1f}ms "
           f"over {len(s.batch_latencies)} batches  [{tails}]")
     # host time per batch by phase (``windve.<tier>.<phase>`` spans): what
-    # the worker did besides waiting on the device
+    # the worker did besides waiting on the device; ``overlapped``: the
+    # batches enqueued while the worker had one in flight (pipelined drain)
     for tier, phases in s.host_ms_per_batch().items():
         print(f"[serve] host ms/batch {tier}: " + " ".join(
-            f"{p}={phases[p]:.3f}" for p in PHASES if p in phases))
+            f"{p}={phases[p]:.3f}" for p in PHASES if p in phases)
+            + f"  overlapped={s.overlapped_batches.get(tier, 0)}"
+            f"/{s.spans(tier).batches}")
     submit_us = s.host_us_per_submit()
     if submit_us is not None:
         print(f"[serve] host submit: {submit_us:.1f}us/query")
@@ -457,8 +475,8 @@ def main() -> None:
     ap.add_argument("--policy", default="cascade", choices=sorted(POLICIES),
                     help="dispatch policy (cascade == paper Algorithm 1)")
     ap.add_argument("--opt", default="",
-                    help="perf flags, e.g. embed_dtype=int8_w8a8,embed_async=1"
-                         ",cache=4096,cache_bytes=0 "
+                    help="perf flags, e.g. embed_dtype=int8_w8a8,"
+                         "cache=4096,cache_bytes=0 "
                          "(embed_dtype: fp32|bf16|int8|int8_w8a8; cache=N "
                          "puts an N-entry exact-match embedding cache at "
                          "the head of the dispatch topology); fault "

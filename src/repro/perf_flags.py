@@ -81,10 +81,6 @@ class PerfFlags:
     # fresh HBM per batch.  No-op (with the warning suppressed) on backends
     # that cannot alias, e.g. this CPU container.
     embed_donate: bool = False
-    # embedding serving: enqueue the embed and return a fetch handle so the
-    # engine worker overlaps batch N's compute with batch N-1's
-    # device->host fetch (double buffering) instead of blocking per batch.
-    embed_async: bool = False
     # serving: N > 0 puts an exact-match embedding cache of N entries at
     # the head of the dispatch topology (token-hash keyed LRU, zero-latency
     # TierSpec — repro.core.cache).  Hits serve the stored embedding

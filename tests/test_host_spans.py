@@ -28,7 +28,7 @@ from bench import spans as bspans  # noqa: E402
 
 MAX_TOKENS = 32
 BATCH_PHASES = ("stage", "fetch", "complete", "hooks")
-PATHS = [False, True]     # embed_async off (the benchmark's) and on
+PATHS = [False, True]     # the synchronous drain and the pipelined one
 
 
 @pytest.fixture(scope="module")

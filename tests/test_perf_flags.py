@@ -126,9 +126,11 @@ def test_parse_opt_roundtrip():
 
 
 def test_parse_opt_embed_serving_flags():
-    kw = perf_flags.parse_opt("embed_dtype=bf16,embed_donate=1,embed_async=0")
-    assert kw == {"embed_dtype": "bf16", "embed_donate": True,
-                  "embed_async": False}
+    kw = perf_flags.parse_opt("embed_dtype=bf16,embed_donate=1")
+    assert kw == {"embed_dtype": "bf16", "embed_donate": True}
+    # the drain follows the tier's platform, not a flag
+    with pytest.raises(ValueError, match="embed_async"):
+        perf_flags.parse_opt("embed_async=0")
     flags = perf_flags.set_flags(**kw)
     assert flags.embed_dtype == "bf16" and flags.embed_donate
     perf_flags.reset_flags()

@@ -277,9 +277,8 @@ class TestInt8Backends:
             perf_flags.reset_flags()
 
     def test_parse_opt_int8_roundtrip(self):
-        kw = perf_flags.parse_opt("embed_dtype=int8,embed_donate=1,"
-                                  "embed_async=1")
-        assert kw["embed_dtype"] == "int8"
+        kw = perf_flags.parse_opt("embed_dtype=int8,embed_donate=1")
+        assert kw == {"embed_dtype": "int8", "embed_donate": True}
         flags = perf_flags.set_flags(**kw)
         assert flags.embed_dtype == "int8"
         perf_flags.reset_flags()
@@ -384,9 +383,8 @@ class TestW8A8Backends:
             perf_flags.reset_flags()
 
     def test_parse_opt_w8a8_roundtrip(self):
-        kw = perf_flags.parse_opt("embed_dtype=int8_w8a8,embed_donate=1,"
-                                  "embed_async=1")
-        assert kw["embed_dtype"] == "int8_w8a8"
+        kw = perf_flags.parse_opt("embed_dtype=int8_w8a8,embed_donate=1")
+        assert kw == {"embed_dtype": "int8_w8a8", "embed_donate": True}
         flags = perf_flags.set_flags(**kw)
         assert flags.embed_dtype == "int8_w8a8"
         perf_flags.reset_flags()

@@ -1,6 +1,6 @@
 """Device-sharded serving path: serve-mode spec rules, mesh degrade
 behaviour, bf16-vs-fp32 parity, donation/async correctness, staging reuse
-and the engine's double-buffered worker.
+and the engine's pipelined worker.
 
 The spec-rule tests use the FakeMesh idiom from ``test_sharding`` (axis
 names/sizes only, no real devices); the real multi-device mesh runs in a
@@ -143,16 +143,21 @@ class TestShardedBackendSingleDevice:
 
         cfg, params = bge_smoke
         try:
-            perf_flags.set_flags(embed_dtype="bf16", embed_donate=True,
-                                 embed_async=True)
+            perf_flags.set_flags(embed_dtype="bf16", embed_donate=True)
             be = ShardedEmbedderBackend(cfg, params, max_tokens=MAX_TOKENS)
             assert be.serve_dtype == jnp.bfloat16
-            assert be.donate and be.async_dispatch
+            assert be.donate
+            # the drain follows the mesh's platform: a CPU mesh drains
+            # synchronously whatever the flags say
+            assert be.platform == "cpu" and not be.async_dispatch
         finally:
             perf_flags.reset_flags()
         base = ShardedEmbedderBackend(cfg, params, max_tokens=MAX_TOKENS)
         assert base.serve_dtype == jnp.float32
         assert not base.donate and not base.async_dispatch
+        forced = ShardedEmbedderBackend(cfg, params, max_tokens=MAX_TOKENS,
+                                        async_dispatch=True)
+        assert forced.async_dispatch
 
     def test_staging_ring_bounded_and_reused_per_bucket(self, bge_smoke):
         cfg, params = bge_smoke
@@ -245,7 +250,7 @@ class TestStagingOverrun:
             t.start()
         for t in threads:
             t.join(timeout=60)
-        # default staging_slots (4) covers 2 double-buffered workers; three
+        # default staging_slots (4) covers 2 pipelined workers; three
         # must either trip the guard loudly or still serve correct vectors
         assert errors, "3 workers on default staging_slots went unguarded"
         for e in errors:
@@ -311,10 +316,10 @@ class TestStagingOverrun:
         assert not be._staging_pending
 
 
-# ------------------------------------------------ engine double buffering --
+# ---------------------------------------------- engine pipelined drain --
 class TestEngineAsyncWorker:
     def test_async_backend_serves_correct_futures(self, bge_smoke):
-        """The double-buffered worker must hand every future ITS OWN batch's
+        """The pipelined worker must hand every future ITS OWN batch's
         embedding (a lag bug would rotate results between batches)."""
         cfg, params = bge_smoke
         be = ShardedEmbedderBackend(cfg, params, max_tokens=32,
