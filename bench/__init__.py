@@ -3,5 +3,6 @@
 
 Cells, metrics and bounds are listed in ``BENCHMARK.json`` at the root of
 the checkout; each configuration, traffic mix and metric has a file of its
-own here, found by its name.
+own here, found by its name, and so has each architecture that a
+configuration names (``bench/arch/<arch>.py``).
 """

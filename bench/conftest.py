@@ -1,10 +1,13 @@
 """CPU fixtures for the benchmark's own tests.
 
-The harness runs here at a small width of the program's configurations
-(``small_program``), from a copy of ``bench/`` whose configuration files
-state that width and whose mixes run at a rate the host CPU serves
-(``bench_root``).  JAX's persistent compile cache stays off.
+The harness runs here at each configuration's small cut, which its
+architecture module states (``bench/arch/<arch>.py``, ``SMALL``), from a
+copy of ``bench/`` whose configuration files state that cut and whose mixes
+run at a rate the host CPU serves (``bench_root``), and the program serves
+the sizes that the file it runs states (``small_program``).  JAX's
+persistent compile cache stays off.
 """
+import dataclasses
 import json
 import os
 import shutil
@@ -20,10 +23,7 @@ for _p in (str(ROOT), str(ROOT / "src")):
 
 import pytest  # noqa: E402
 
-# the program's configurations at a width the CPU tests can run: two
-# layers, every width cut, multi-head attention as at full width
-SMALL = dict(num_hidden_layers=2, hidden_size=128, num_attention_heads=4,
-             head_dim=32, intermediate_size=256, vocab_size=512)
+from bench import arch  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -48,12 +48,34 @@ DEPTH = 256
 SMALL_LIMITS = {"bias": 0.003, "max_l2_gap": 0.05}
 
 
+def small_cut(cfg: dict) -> dict:
+    """The configuration at its architecture's small cut."""
+    return dict(cfg, **arch.of(cfg).SMALL)
+
+
+def program_config(cfg: dict):
+    """The program's configuration of ``cfg["model"]`` at the sizes ``cfg``
+    states: every whole number among the architecture's ``program_fields``
+    set to the file's (``resolved_<x>`` through its field ``<x>``)."""
+    from repro.configs import get_config
+
+    pcfg = get_config(cfg["model"])
+    names = {f.name for f in dataclasses.fields(pcfg)}
+    sizes = {}
+    for attr, want in arch.of(cfg).program_fields(cfg).items():
+        field = attr.removeprefix("resolved_")
+        if type(want) is int and field in names:
+            sizes[field] = want
+    return pcfg.replace(**sizes)
+
+
 @pytest.fixture
 def small_program(monkeypatch):
-    """``build_engine`` serves the small width of each configuration, with
-    a fixed depth per tier: an Eq. 12 sweep timed on a loaded test host
-    can refuse its own fit, which says nothing about the harness."""
-    from repro.configs import get_config
+    """``bench.run.build`` builds the program at the sizes of the
+    configuration it is given, with a fixed depth per tier: an Eq. 12
+    sweep timed on a loaded test host can refuse its own fit, which says
+    nothing about the harness."""
+    from bench import run
     from repro.core.estimator import fit_latency
     from repro.launch import serve
 
@@ -61,16 +83,14 @@ def small_program(monkeypatch):
         profile(points[0])           # runs the tier once, as a sweep would
         return DEPTH, fit_latency([1, DEPTH], [0.01, slo])
 
-    def small(name):
-        return get_config(name).replace(
-            num_layers=SMALL["num_hidden_layers"],
-            d_model=SMALL["hidden_size"],
-            num_heads=SMALL["num_attention_heads"],
-            num_kv_heads=SMALL["num_attention_heads"],
-            head_dim=SMALL["head_dim"], d_ff=SMALL["intermediate_size"],
-            vocab_size=SMALL["vocab_size"])
+    build = run.build
 
-    monkeypatch.setattr(serve, "get_config", small)
+    def small_build(cfg, seed):
+        with monkeypatch.context() as m:
+            m.setattr(serve, "get_config", lambda name: program_config(cfg))
+            return build(cfg, seed)
+
+    monkeypatch.setattr(run, "build", small_build)
     monkeypatch.setattr(serve, "calibrate", calibrate)
 
 
@@ -81,17 +101,16 @@ def write_json(path: Path, obj) -> None:
 
 @pytest.fixture
 def bench_root(tmp_path):
-    """A checkout holding ``BENCHMARK.json`` and a copy of ``bench/`` at the
-    small width and its limits, with short warm-ups and a low open-loop
-    rate."""
+    """A checkout holding ``BENCHMARK.json`` and a copy of ``bench/`` at
+    each configuration's small cut and the small limits, with short
+    warm-ups and a low open-loop rate."""
     root = tmp_path / "checkout"
     shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
     for f in (root / "bench" / "configs").glob("*.json"):
-        cfg = json.loads(f.read_text())
-        cfg.update(SMALL, limits=SMALL_LIMITS)
-        write_json(f, cfg)
+        cfg = small_cut(json.loads(f.read_text()))
+        write_json(f, dict(cfg, limits=SMALL_LIMITS))
     for f in (root / "bench" / "traffic").glob("*.json"):
         mix = json.loads(f.read_text())
         mix["warmup_s"] = 0.5

@@ -1,26 +1,22 @@
 """Operations and bytes the served work requires, computed from shapes.
 
-Counts follow the configuration file's encoder (``bench/configs``): per
-layer the Q, K, V and output projections, the two FFN matmuls, and the
-attention scores and weighted values over the query's own real tokens.
-A multiply-add is two operations.  Padding, elementwise work, norms and the
-embedding gather are not counted: these are the operations the real tokens
-require, not what an implementation happens to execute.
+An encoder's matmul count is its architecture module's
+(``bench/arch/<arch>.py``, ``encoder_flops``); pooling and normalisation
+are counted here.  A multiply-add is two operations.  Padding, elementwise
+work, norms and the embedding gather are not counted: these are the
+operations the real tokens require, not what an implementation happens to
+execute.
 """
 from __future__ import annotations
 
 from typing import Iterable, Tuple
 
+from bench import arch
+
 
 def encoder_flops(length: int, cfg: dict) -> int:
     """Matmul operations of one sequence of ``length`` real tokens."""
-    d = cfg["hidden_size"]
-    inner = cfg["num_attention_heads"] * cfg["head_dim"]
-    ffn = cfg["intermediate_size"]
-    proj = 2 * length * d * inner * 4             # q, k, v, o
-    mlp = 2 * length * d * ffn * 2                # in, out
-    attn = 2 * length * length * inner * 2        # q.k^T and p.v
-    return cfg["num_hidden_layers"] * (proj + mlp + attn)
+    return arch.of(cfg).encoder_flops(length, cfg)
 
 
 def batch_flops(lengths: Iterable[int], cfg: dict) -> int:

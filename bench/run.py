@@ -16,7 +16,8 @@ The cell (``BENCHMARK.json``) names a configuration
    timing every query from when it was due to its future's completion;
 4. with ``--trace 1``, traces the window with ``jax.profiler``;
 5. compares a seeded sample of the embeddings served in the window with
-   ``bench/reference.py`` and prints one JSON line.
+   ``bench/reference.py``, which runs the configuration's architecture
+   module (``bench/arch/<arch>.py``), and prints one JSON line.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics; each metric is read by
@@ -55,7 +56,7 @@ for _p in (str(ROOT), str(ROOT / "src")):
 
 import numpy as np  # noqa: E402
 
-from bench import loadgen, reference, stats  # noqa: E402
+from bench import arch, loadgen, reference, stats  # noqa: E402
 from bench import trace as btrace  # noqa: E402
 
 TRACE_DIR = ROOT / ".bench_trace"
@@ -202,50 +203,34 @@ class CompileClock:
 
 
 def check_program_config(pcfg, cfg: dict) -> None:
-    """The program's configuration must be the file's, size for size."""
-    want = {"num_layers": cfg["num_hidden_layers"],
-            "d_model": cfg["hidden_size"],
-            "num_heads": cfg["num_attention_heads"],
-            "num_kv_heads": cfg["num_attention_heads"],
-            "resolved_head_dim": cfg["head_dim"],
-            "d_ff": cfg["intermediate_size"],
-            "vocab_size": cfg["vocab_size"],
-            "norm_eps": cfg["layer_norm_eps"],
-            "pool": cfg["pooling"], "act": "gelu", "norm": "layernorm"}
+    """The program's configuration must be the one that the configuration's
+    architecture module expects of the file, attribute for attribute."""
+    want = arch.of(cfg).program_fields(cfg)
     got = {k: getattr(pcfg, k) for k in want}
     if got != want:
         raise RuntimeError(f"the program serves {got}, the configuration "
                            f"file states {want}")
 
 
-def seeded_init(init, std: float):
-    """The program's ``init_embedder`` with the token table drawn anew as
-    normal * ``std`` from the table's own key, as ``bench/reference.py``
-    draws it; every other leaf is the program's."""
-    import jax
-    import jax.numpy as jnp
-
-    def init_params(key, pcfg, dtype=jnp.float32):
-        params = init(key, pcfg, dtype)
-        table = jax.random.split(key, 3)[1]
-        params["embed"] = (jax.random.normal(
-            table, params["embed"].shape, jnp.float32) * std).astype(dtype)
-        return params
-
-    return init_params
+def program_init(mod):
+    """(module, attribute name) of the program's initialisation that the
+    architecture module's ``seeded_init`` wraps (``PROGRAM_INIT``)."""
+    where, name = mod.PROGRAM_INIT.rsplit(".", 1)
+    return importlib.import_module(where), name
 
 
 def build(cfg: dict, seed: int):
     """The engine, as ``repro.launch.serve.build_engine`` builds it with the
-    configuration's token table, and the seconds its parameter
-    initialisation took."""
+    program's initialisation wrapped by the architecture's ``seeded_init``,
+    and the seconds its parameter initialisation took."""
     import jax
 
     from repro.launch import serve
-    from repro.models import embedder
 
-    init, spent = embedder.init_embedder, [0.0]
-    draw = seeded_init(init, cfg["embedding_init_std"])
+    mod = arch.of(cfg)
+    owner, name = program_init(mod)
+    init, spent = getattr(owner, name), [0.0]
+    draw = mod.seeded_init(init, cfg["embedding_init_std"])
 
     def timed_init(*a, **k):
         t = time.monotonic()
@@ -253,13 +238,13 @@ def build(cfg: dict, seed: int):
         spent[0] += time.monotonic() - t
         return out
 
-    embedder.init_embedder = timed_init
+    setattr(owner, name, timed_init)
     try:
         with contextlib.redirect_stdout(sys.stderr):
             engine, pcfg = serve.build_engine(
                 cfg["model"], slo=cfg["slo_s"], seed=seed, prewarm=False)
     finally:
-        embedder.init_embedder = init
+        setattr(owner, name, init)
     check_program_config(pcfg, cfg)
     return engine, spent[0]
 
@@ -493,6 +478,7 @@ def run_cell(root: Path, name: str, seed: int, seconds: float, trace: bool,
     that breaks the built engine before any traffic."""
     bench, cell, cfg_file, mix = cell_spec(root, name, bench)
     cfg = dict(config if config is not None else cfg_file)
+    arch.load(root, cfg["arch"])
     if precision is not None:
         cfg["precision"] = precision
     if require_tpu:

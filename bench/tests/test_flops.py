@@ -1,9 +1,15 @@
 """The yardstick's operation and byte counts against hand counts."""
+import json
+from pathlib import Path
+
 from bench import flops
 
 # two layers, d = 8, two heads of 4, FFN 16
-CFG = dict(num_hidden_layers=2, hidden_size=8, num_attention_heads=2,
-           head_dim=4, intermediate_size=16, pooling="cls")
+CFG = dict(arch="prenorm_encoder", num_hidden_layers=2, hidden_size=8,
+           num_attention_heads=2, head_dim=4, intermediate_size=16,
+           pooling="cls")
+BGE = json.loads((Path(__file__).resolve().parents[1] / "configs"
+                  / "bge-large-zh-v1.5.json").read_text())
 
 
 def test_encoder_flops_matches_a_hand_count():
@@ -47,3 +53,10 @@ def test_pool_norm_work_mean_needs_every_real_row():
     assert ops == 10 * 8 + 8 + 3 * 8 + 1
     # 10 bf16 rows, 10 float32 mask values, one float32 output row
     assert nbytes == 10 * 8 * 2 + 10 * 4 + 8 * 4
+
+
+def test_bge_counts_are_pinned():
+    """A (64, 75) batch of bge-large-zh-v1.5, as the counts read before the
+    encoder's count moved into its architecture module."""
+    assert flops.batch_flops([75] * 64, BGE) == 2934492364800
+    assert flops.pool_norm_work([75] * 64, BGE) == (196672, 393216)

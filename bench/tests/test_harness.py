@@ -8,8 +8,8 @@ from pathlib import Path
 
 import pytest
 
-from bench import run
-from bench.conftest import write_json
+from bench import arch, flops, run
+from bench.conftest import small_cut, write_json
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2 ** 31 + 77
@@ -22,6 +22,11 @@ def test_every_name_in_the_benchmark_has_its_file():
     for w in bench["workloads"]:
         _, cell, cfg, mix = run.cell_spec(ROOT, w["name"])
         assert cfg["name"] == cell["config"]
+        mod = arch.load(ROOT, cfg["arch"])
+        for name in ("init_weights", "forward", "encoder_flops",
+                     "program_fields", "seeded_init"):
+            assert callable(getattr(mod, name))
+        assert mod.PROGRAM_INIT and mod.SMALL
         assert cfg["limits"] and set(cfg["limits"]) <= {"max_l2_gap", "bias"}
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert callable(run.reader(ROOT, m["name"]))
@@ -69,6 +74,71 @@ def test_a_cell_config_mix_and_metric_added_as_files_run(bench_root,
     assert out["attempted"] > 100
     assert list(out)[-1] == "check"
     assert out["device"]["platform"] == "cpu"
+
+
+# a stand-in architecture: prenorm_encoder's block with an operation count,
+# a small cut and a program field of its own, and a reference that negates
+# every answer
+TOY_ARCH = '''from bench.arch import prenorm_encoder as base
+
+PROGRAM_INIT = base.PROGRAM_INIT
+SMALL = dict(base.SMALL, num_hidden_layers=3)
+init_weights = base.init_weights
+seeded_init = base.seeded_init
+
+
+def forward(cfg):
+    fwd = base.forward(cfg)
+    return lambda weights, tokens, mask: -fwd(weights, tokens, mask)
+
+
+def encoder_flops(length, cfg):
+    return 7 * length
+
+
+def program_fields(cfg):
+    return dict(base.program_fields(cfg), rope_theta=cfg["rope_theta"])
+'''
+
+
+def test_an_architecture_added_as_files_brings_its_own_parts(
+        bench_root, small_program, monkeypatch):
+    """A configuration whose ``bench/arch/<arch>.py`` is new runs with that
+    module's reference, operation count, program check and small cut, and
+    nothing else under ``bench/`` changes."""
+    b = bench_root / "bench"
+    (b / "arch" / "toy_negated.py").write_text(TOY_ARCH)
+    arch.load(bench_root, "toy_negated")
+    bge = json.loads((b / "configs" / "bge-large-zh-v1.5.json").read_text())
+    cfg = small_cut(dict(bge, name="toy-negated", arch="toy_negated",
+                         rope_theta=0.0))
+    assert cfg["num_hidden_layers"] == 3
+    write_json(b / "configs" / "toy-negated.json", cfg)
+    bench = json.loads((bench_root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "toy-negated", "source": "x",
+                             "file": "bench/configs/toy-negated.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "toy.ingest", "config": "toy-negated",
+                               "traffic": "ingest", "chips": 1,
+                               "why": "test"})
+    (bench_root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    toy = run.run_cell(bench_root, "toy.ingest", SEED, 1.0, False,
+                       require_tpu=False)
+    assert toy["correct"] is False
+    assert toy["check"]["max_l2_gap"]["value"] > 1.9
+    assert flops.batch_flops([10, 20], cfg) == 7 * 30
+    ok = run.run_cell(bench_root, "bge.ingest", SEED, 1.0, False,
+                      require_tpu=False)
+    assert ok["correct"] is True, ok["check"]
+
+    def window(*a, **k):
+        pytest.fail("the window opened")
+
+    monkeypatch.setattr(run, "Driver", window)
+    with pytest.raises(RuntimeError, match="rope_theta"):
+        run.run_cell(bench_root, "toy.ingest", SEED, 1.0, False,
+                     require_tpu=False, config=dict(cfg, rope_theta=1e4))
 
 
 def test_no_tpu_means_no_result(capsys):

@@ -8,11 +8,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference
-from bench.conftest import SMALL
+from bench import arch, reference
+from bench.conftest import program_config as program
+from bench.conftest import small_cut
 
 CONFIGS = sorted((Path(__file__).resolve().parents[1] / "configs")
                  .glob("*.json"))
+# the configurations of the pre-LN block, whose leaves the program names as
+# ``test_weights_are_the_programs_own`` reads them
+PRENORM = [p for p in CONFIGS
+           if json.loads(p.read_text())["arch"] == "prenorm_encoder"]
 SEED = 2 ** 31 + 5
 # float32 against float32: the program's chunked online-softmax attention
 # and fused reductions sum in another order than the reference, about 2e-7
@@ -22,19 +27,7 @@ FP32_TOL = 1e-5
 
 
 def small_cfg(path):
-    cfg = json.loads(Path(path).read_text())
-    cfg.update(SMALL)
-    return cfg
-
-
-def program(cfg):
-    from repro.configs import get_config
-
-    return get_config(cfg["model"]).replace(
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_attention_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"])
+    return small_cut(json.loads(Path(path).read_text()))
 
 
 def queries(cfg, n=12):
@@ -49,10 +42,11 @@ def served_params(cfg):
     initialisation, with the configuration's token table."""
     import jax
 
-    from bench.run import seeded_init
-    from repro.models import embedder
+    from bench.run import program_init
 
-    init = seeded_init(embedder.init_embedder, cfg["embedding_init_std"])
+    mod = arch.of(cfg)
+    init = mod.seeded_init(getattr(*program_init(mod)),
+                           cfg["embedding_init_std"])
     return init(jax.random.PRNGKey(SEED), program(cfg))
 
 
@@ -72,7 +66,7 @@ def program_embed(cfg, qs, dtype):
                                      jnp.asarray(mask), compute_dtype=cdt))
 
 
-@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+@pytest.mark.parametrize("path", PRENORM, ids=lambda p: p.stem)
 def test_weights_are_the_programs_own(path):
     cfg = small_cfg(path)
     params = served_params(cfg)
@@ -124,3 +118,36 @@ def test_padding_and_blocking_change_nothing():
                         for q in qs])
     np.testing.assert_allclose(a, b, atol=1e-6)
     np.testing.assert_allclose(np.linalg.norm(a, axis=-1), 1.0, atol=1e-6)
+
+
+def test_mixed_lengths_with_a_long_input_block_as_one_at_a_time(monkeypatch):
+    """Longest first, each block padded to its own longest input and cut to
+    fewer rows where a long input's attention scores would pass the budget:
+    the vectors are those of one query at a time, in the order given."""
+    cfg = small_cfg(CONFIGS[0])
+    qs = queries(cfg, n=10)
+    long = np.random.default_rng(3).integers(
+        1, cfg["vocab_size"], size=600).astype(np.int32)
+    qs.insert(4, long)
+    # the scores of two rows of the long input fit, not three
+    monkeypatch.setattr(reference, "SCORE_BUDGET",
+                        2 * 4 * cfg["num_attention_heads"] * 600 ** 2)
+    mod = arch.of(cfg)
+    forward, shapes = mod.forward, []
+
+    def spy(c):
+        fwd = forward(c)
+
+        def call(w, toks, mask):
+            shapes.append(toks.shape)
+            return fwd(w, toks, mask)
+
+        return call
+
+    monkeypatch.setattr(mod, "forward", spy)
+    blocked = reference.embed(cfg, SEED, qs, block=8)
+    lens = sorted((len(q) for q in qs), reverse=True)
+    assert shapes == [(2, 600), (8, lens[2]), (8, lens[10])]
+    one = np.concatenate([reference.embed(cfg, SEED, [q], block=1)
+                          for q in qs])
+    assert reference.l2_gaps(blocked, one).max() < FP32_TOL
