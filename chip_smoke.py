@@ -204,7 +204,7 @@ def one_chip(tpu, cpu, args) -> None:
             params = embedder.init_embedder(jax.random.PRNGKey(args.seed),
                                             cfg)
             cpu_be = ShardedEmbedderBackend(
-                cfg, params, max_tokens=serve.MAX_TOKENS, devices=[cpu],
+                cfg, params, max_tokens=cfg.max_tokens, devices=[cpu],
                 min_seq_bucket=serve.MIN_SEQ_BUCKET)
         check(platforms(cpu_be) == {"cpu"},
               f"offload tier on {platforms(cpu_be)}")
@@ -252,14 +252,14 @@ def four_chips(tpus, args) -> None:
     from repro.core.sharded_backend import ShardedEmbedderBackend
     from repro.data.workload import make_queries
     from repro.launch.mesh import make_replica_meshes
-    from repro.launch.serve import MAX_TOKENS, MIN_SEQ_BUCKET
+    from repro.launch.serve import MIN_SEQ_BUCKET
     from repro.models import embedder
 
     check(len(tpus) >= 4, f"--chips 4 needs 4 TPU chips, found {len(tpus)}")
     tpus = tpus[:4]
     cfg = get_config("bge-large-zh-v1.5")
     params = embedder.init_embedder(jax.random.PRNGKey(args.seed), cfg)
-    kw = dict(max_tokens=MAX_TOKENS, min_seq_bucket=MIN_SEQ_BUCKET)
+    kw = dict(max_tokens=cfg.max_tokens, min_seq_bucket=MIN_SEQ_BUCKET)
     qs = [Query(qid=i, payload=p, length=QUERY_LENGTH) for i, p in
           enumerate(make_queries(32, cfg.vocab_size, QUERY_LENGTH,
                                  seed=args.seed + 1))]
@@ -271,10 +271,10 @@ def four_chips(tpus, args) -> None:
     # (a) one data-parallel backend over the four chips
     dp = ShardedEmbedderBackend(cfg, params, devices=tpus, **kw)
     check(dp.device_count == 4, f"mesh over {dp.device_count} devices")
-    toks, mask, _, _ = dp._stage_chunk(qs, len(qs), MAX_TOKENS)
+    toks, mask, _, _ = dp._stage_chunk(qs, len(qs), cfg.max_tokens)
     out = dp._embed(dp.params, toks, mask)
     out.block_until_ready()
-    dp._release_staging([(len(qs), MAX_TOKENS)])
+    dp._release_staging([(len(qs), cfg.max_tokens)])
     for arr, what in ((toks, "tokens"), (out, "embeddings")):
         shards = {s.device: s.data.shape for s in arr.addressable_shards}
         log(f"4-chip {what} shards: "

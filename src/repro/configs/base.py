@@ -48,9 +48,22 @@ class ModelConfig:
     norm_eps: float = 1e-5
     act: str = "silu"         # silu -> SwiGLU MLP; gelu -> plain GELU MLP
     norm: str = "rmsnorm"     # rmsnorm | layernorm
+    norm_bias: bool = True    # layernorm: a learned bias beside the scale
     tie_embeddings: bool = False
     pool: str = "none"        # embedder pooling: none | cls | mean
     embed_dim: int = 0        # embedder output dim (bge: 1024)
+    # --- encoders with two kinds of attention layer (ModernBERT) ---
+    # layer i attends globally when i % global_every == 0 and over its
+    # local window otherwise; 0 = every layer global
+    global_every: int = 0
+    local_window: int = 0     # a local layer's query attends |i - j| <= this
+    local_rope_theta: float = 0.0   # rope_theta is the global layers'
+    # a LayerNorm on the token embeddings, which takes the place of layer
+    # 0's attention norm (that norm is the identity)
+    embed_norm: bool = False
+    # --- serving ---
+    max_tokens: int = 96      # served context: longer inputs are truncated
+    probe_tokens: int = 75    # input length the Eq. 12 sweep is timed at
     source: str = ""
 
     # ------------------------------------------------------------------
@@ -61,6 +74,10 @@ class ModelConfig:
         if self.num_heads:
             return self.d_model // self.num_heads
         return 0
+
+    def is_global_layer(self, i: int) -> bool:
+        """Does layer ``i`` attend over the whole sequence?"""
+        return not self.global_every or i % self.global_every == 0
 
     @property
     def d_inner(self) -> int:
@@ -119,6 +136,11 @@ class ModelConfig:
             changes["sliding_window"] = 16
         if self.embed_dim:
             changes["embed_dim"] = 64
+        if self.local_window:
+            changes["local_window"] = 8
+        if self.max_tokens > 128:
+            changes["max_tokens"] = 128
+            changes["probe_tokens"] = min(self.probe_tokens, 128)
         return replace(self, **changes)
 
     def replace(self, **kw) -> "ModelConfig":
@@ -155,10 +177,12 @@ ARCH_MODULES = {
     # the paper's own embedding models
     "bge-large-zh-v1.5": "bge_large_zh",
     "jina-v2": "jina_v2",
+    "modernbert-large": "modernbert_large",
 }
 
-ASSIGNED_ARCHS: Tuple[str, ...] = tuple(k for k in ARCH_MODULES if k not in
-                                        ("bge-large-zh-v1.5", "jina-v2"))
+ASSIGNED_ARCHS: Tuple[str, ...] = tuple(
+    k for k in ARCH_MODULES
+    if k not in ("bge-large-zh-v1.5", "jina-v2", "modernbert-large"))
 
 
 def get_config(arch: str) -> ModelConfig:

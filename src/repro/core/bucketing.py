@@ -195,6 +195,16 @@ class BucketedEmbedderBackend(JaxEmbedderBackend):
             return (self._jnp.asarray(toks), self._jnp.asarray(mask), real,
                     truncated)
 
+    def _record_attended(self, chunk: Sequence[Query]) -> None:
+        """Count the key positions the chunk's real tokens attend, per kind
+        of layer, once an engine has wired the tier's telemetry."""
+        if self.tier is None or self.telemetry is None:
+            return
+        from repro.models.embedder import attended_keys
+
+        self.telemetry.record_attended_keys(self.tier, attended_keys(
+            self.cfg, [min(self._qlen(q), self.max_tokens) for q in chunk]))
+
     def _release_staging(self, keys) -> None:
         """Hand staged buckets back once their execution is done (fresh host
         arrays here need no bookkeeping; the sharded backend's ring does)."""
@@ -225,6 +235,7 @@ class BucketedEmbedderBackend(JaxEmbedderBackend):
                 toks, mask, real, truncated = self._stage_chunk(chunk, bb, sb,
                                                                 spans)
                 self._record_truncations(truncated)
+                self._record_attended(chunk)
                 with self._bucket_lock:
                     if (bb, sb) in self._buckets:
                         self.bucket_hits += 1

@@ -158,6 +158,9 @@ class Telemetry:
     # the threaded engine's pipelined drain: per tier, batches enqueued
     # while the same worker still had a batch in flight
     overlapped_batches: Dict[str, int] = field(default_factory=dict)
+    # key positions the real tokens attended, keyed "<kind>_<tier>" with
+    # kind ``global`` or ``local`` (``embedder.attended_keys``)
+    attended_keys: Dict[str, int] = field(default_factory=dict)
     failed: int = 0              # queries whose futures terminally failed
     hook_errors: int = 0         # batch hooks that raised (and were caught)
     # overload-control counters: rejections broken down by reason
@@ -256,6 +259,15 @@ class Telemetry:
         with self._lock:
             self.overlapped_batches[tier] = \
                 self.overlapped_batches.get(tier, 0) + 1
+
+    def record_attended_keys(self, tier: str, counts: Dict[str, int]
+                             ) -> None:
+        """Key positions a staged chunk's real tokens attend, per kind of
+        layer (``repro.models.embedder.attended_keys``)."""
+        with self._lock:
+            for kind, n in counts.items():
+                key = f"{kind}_{tier}"
+                self.attended_keys[key] = self.attended_keys.get(key, 0) + n
 
     def record_batch(self, tier: str, service_s: float) -> None:
         """One batch execution's service latency (its pop, or the previous
@@ -459,7 +471,8 @@ class Telemetry:
         threaded engine's host phases join as ``host_ms_<phase>_<tier>``
         (milliseconds per batch) and ``host_us_submit`` once timed, each
         tier's popped ``batches_<tier>`` with ``overlapped_batches_<tier>``,
-        those enqueued while the worker had a batch in flight."""
+        those enqueued while the worker had a batch in flight, and
+        ``attended_keys_<kind>_<tier>`` once counted."""
         fault: Dict[str, float] = {}
         if (self.deadline_misses or self.retries or self.backend_errors
                 or self.breaker_trips or self.breaker_recoveries
@@ -511,6 +524,8 @@ class Telemetry:
         submit_us = self.host_us_per_submit()
         if submit_us is not None:
             host["host_us_submit"] = submit_us
+        host.update({f"attended_keys_{k}": v
+                     for k, v in sorted(self.attended_keys.items())})
         return {
             **fault,
             **overload,

@@ -36,7 +36,8 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     if causal:
         valid &= kp <= qp
     if window:
-        valid &= kp > qp - window
+        # causal: keys (i - window, i]; bidirectional: |i - j| <= window
+        valid &= (kp > qp - window) if causal else (jnp.abs(kp - qp) <= window)
     s = jnp.where(valid[None, None, None], s, -jnp.inf)
     if kv_len is not None:
         kvalid = jnp.arange(Sk)[None, :] < kv_len[:, None]          # (B, Sk)

@@ -69,7 +69,6 @@ POLICIES = {
     "round-robin": RoundRobinPolicy,
 }
 
-MAX_TOKENS = 96
 MIN_SEQ_BUCKET = 16
 QUERY_LENGTH = 75        # the paper's query length
 # rows one execution serves, and so the top of the prewarmed (B, S) grid:
@@ -78,6 +77,14 @@ QUERY_LENGTH = 75        # the paper's query length
 # (and its compile time) small
 ACCEL_MAX_BATCH = 64
 CPU_MAX_BATCH = 8
+# ... and tokens one execution serves: 64 rows of bge-large's 96, or 8 of
+# ModernBERT-large's 8,192 (its GeGLU intermediate is then 0.7 GB in bf16)
+TOKEN_BUDGET = 65536
+# the offload tier's Eq. 11 check probes its smallest batch from inputs
+# this long, doubling up to the calibration length: a tier that misses the
+# SLO on a prefix of the input misses it on the whole input, and one long
+# input on the host CPU can take minutes
+CHECK_FROM_TOKENS = 128
 # calibration re-probes an extrapolated depth: each round probes 5% past
 # the fitted depth, never more than 4x the largest point measured so far
 REPROBE_PAST = 1.05
@@ -85,19 +92,22 @@ REPROBE_REACH = 4
 REPROBE_ROUNDS = 4
 
 
-def max_batch_for(platform: str) -> int:
-    return CPU_MAX_BATCH if platform == "cpu" else ACCEL_MAX_BATCH
+def max_batch_for(platform: str, max_tokens: int) -> int:
+    """Rows one execution of a tier on ``platform`` serves, for inputs of
+    up to ``max_tokens`` tokens."""
+    rows = CPU_MAX_BATCH if platform == "cpu" else ACCEL_MAX_BATCH
+    return max(1, min(rows, TOKEN_BUDGET // max_tokens))
 
 
-def profile_fn(backend, vocab: int, max_batch: int, seed: int = 0
-               ) -> Callable[..., float]:
+def profile_fn(backend, vocab: int, max_batch: int, seed: int = 0,
+               length: int = QUERY_LENGTH) -> Callable[..., float]:
     """Eq. 12 probe of a real backend: the seconds it takes to serve ``c``
     queued queries of ``length`` tokens in batches of at most
     ``max_batch``, the way the engine's worker drains its queue: on a
     pipelined backend (``windve.pipelines``) chunk k+1 is enqueued before
     chunk k is fetched, on any other chunk by chunk.  Every shape is run
     once before the clock starts, so a probe times service and never
-    compilation; the best of two timed passes is returned."""
+    compilation; the best of ``passes`` timed passes is returned."""
     pipelined = pipelines(backend)
 
     def drain(chunks) -> None:
@@ -113,14 +123,14 @@ def profile_fn(backend, vocab: int, max_batch: int, seed: int = 0
             fetch = nxt
         fetch()
 
-    def profile(c: int, length: int = QUERY_LENGTH) -> float:
+    def profile(c: int, length: int = length, passes: int = 2) -> float:
         qs = [Query(qid=i, payload=p, length=length) for i, p in
               enumerate(make_queries(c, vocab, length=length, seed=seed))]
         chunks = [qs[i:i + max_batch] for i in range(0, c, max_batch)]
         for chunk in chunks:             # compile before timing
             backend.embed_batch(chunk)
         best = float("inf")
-        for _ in range(2):
+        for _ in range(passes):
             t0 = time.monotonic()
             drain(chunks)
             best = min(best, time.monotonic() - t0)
@@ -137,9 +147,30 @@ def probe_points(max_batch: int, floor: int = 1) -> tuple:
     return tuple(max(floor, max_batch * k // 4) for k in (1, 2, 4, 8))
 
 
+def slo_check(profile: Callable[..., float], slo: float, length: int):
+    """``check(c) -> (seconds, tokens)``: ``c`` queries timed once each (after
+    their compile) at inputs of ``CHECK_FROM_TOKENS`` tokens, doubling up
+    to ``length`` (or at ``length`` alone, if that is shorter), stopping at
+    the first length that misses ``slo``."""
+
+    def check(c: int):
+        n = min(length, CHECK_FROM_TOKENS)
+        while True:
+            t = profile(c, n, passes=1)
+            if t > slo or n >= length:
+                return t, n
+            n = min(2 * n, length)
+
+    return check
+
+
 def calibrate(name: str, profile: Callable[[int], float], slo: float,
-              points: tuple):
+              points: tuple, check: Optional[Callable] = None):
     """Eq. 12 depth of one tier, read inside the range it was measured on.
+
+    ``check`` (``slo_check``), when given, first times the smallest probe
+    batch alone: if it misses the SLO, the tier's depth is 0 (Eq. 11) and
+    the fit None, with no sweep.
 
     A flat fit (no growth across the probe points) yields the estimator's
     unbounded sentinel: that is a broken measurement, and serving it would
@@ -148,6 +179,12 @@ def calibrate(name: str, profile: Callable[[int], float], slo: float,
     is probed again just past that depth (at most ``REPROBE_REACH`` times
     the largest point so far) and the line refit over every point, until
     the depth lies within the probed range."""
+    if check is not None:
+        t, n = check(points[0])
+        if t > slo:
+            print(f"[serve] {name}: {points[0]} queries of {n} tokens took "
+                  f"{t:.2f}s, past the {slo}s SLO: depth 0 (Eq. 11)")
+            return 0, None
     measured = {}
     for _ in range(REPROBE_ROUNDS + 1):
         for c in points:
@@ -190,25 +227,35 @@ def build_engine(model: str = "bge-large-zh-v1.5", slo: float = 1.0,
           f"heter={det.heter_enable}")
     if det.device_main == "none":
         raise RuntimeError("no device to serve from")
-    bucket_fn = length_bucket_fn(MIN_SEQ_BUCKET, MAX_TOKENS)
+    bucket_fn = length_bucket_fn(MIN_SEQ_BUCKET, cfg.max_tokens)
+
+    def max_batch(be) -> int:
+        return max_batch_for(be.platform, cfg.max_tokens)
 
     def real_backend(devs) -> ShardedEmbedderBackend:
-        be = ShardedEmbedderBackend(cfg, params, max_tokens=MAX_TOKENS,
+        be = ShardedEmbedderBackend(cfg, params, max_tokens=cfg.max_tokens,
                                     devices=devs,
                                     min_seq_bucket=MIN_SEQ_BUCKET)
-        mb = max_batch_for(be.platform)
         print(f"[serve] backend {be.name}: {be.device_count} "
-              f"{be.platform} device(s), batches <= {mb}")
-        if prewarm:
-            n = be.prewarm(be.warm_grid(max_batch=mb))
-            print(f"[serve] prewarmed {n} (B, S) buckets of {be.name}")
+              f"{be.platform} device(s), batches <= {max_batch(be)}")
         return be
 
-    def calibrate_real(name: str, be: ShardedEmbedderBackend):
-        mb = max_batch_for(be.platform)
-        prof = profile_fn(be, cfg.vocab_size, mb, seed)
-        depth, fit = calibrate(name, prof, slo,
-                               probe_points(mb, be.min_batch_bucket))
+    def warm(be) -> None:
+        if prewarm:
+            n = be.prewarm(be.warm_grid(max_batch=max_batch(be)))
+            print(f"[serve] prewarmed {n} (B, S) buckets of {be.name}")
+
+    def calibrate_real(name: str, be: ShardedEmbedderBackend,
+                       offload: bool = False):
+        prof = profile_fn(be, cfg.vocab_size, max_batch(be), seed,
+                          length=cfg.probe_tokens)
+        points = probe_points(max_batch(be), be.min_batch_bucket)
+        if offload:
+            depth, fit = calibrate(name, prof, slo, points,
+                                   check=slo_check(prof, slo,
+                                                   cfg.probe_tokens))
+        else:
+            depth, fit = calibrate(name, prof, slo, points)
         return depth, fit, prof
 
     # the main tier, expanded to hosts x replicas first-class tiers
@@ -234,25 +281,32 @@ def build_engine(model: str = "bge-large-zh-v1.5", slo: float = 1.0,
         pool = _serve_devices(pool[:devices] if devices else pool)
         main_backends = [real_backend(g)
                          for g in replica_groups(hosts, replicas, pool)]
+        for be in main_backends:
+            warm(be)
         main_be = main_backends[0]
 
         def main_backend(h: int, r: int) -> ShardedEmbedderBackend:
             return main_backends[h * replicas + r]
 
         d_main, fit_main, _ = calibrate_real(main, main_be)
-        spec_kw = dict(max_batch=max_batch_for(main_be.platform),
-                       bucket_fn=bucket_fn)
+        spec_kw = dict(max_batch=max_batch(main_be), bucket_fn=bucket_fn)
     d_main = max(d_main, 1)
     main_spec = TierSpec(main, d_main, backend=main_be, **spec_kw)
 
     d_cpu, fit_c, cpu_be = 0, None, None
     if det.heter_enable:
         cpu_be = real_backend(list(inv.cpu_devices))
-        d_cpu, fit_c, cpu_profile = calibrate_real(CPU, cpu_be)
+        d_cpu, fit_c, cpu_profile = calibrate_real(CPU, cpu_be, offload=True)
+        if d_cpu > 0:
+            warm(cpu_be)
+        else:               # the offload tier is left out of the engine
+            cpu_be = None
     print(f"[serve] depths: C_{main}={d_main} "
           f"(a={fit_main.alpha:.5f} b={fit_main.beta:.4f})"
           + (f" C_CPU={d_cpu} (a={fit_c.alpha:.5f} b={fit_c.beta:.4f})"
-             if fit_c else ""))
+             if fit_c else "")
+          + (" C_CPU=0 (its smallest probe batch misses the SLO)"
+             if det.heter_enable and not fit_c else ""))
 
     main_tiers = replicate(main_spec, hosts, replicas, backend=main_backend)
     if len(main_tiers) > 1:
@@ -284,14 +338,14 @@ def build_engine(model: str = "bge-large-zh-v1.5", slo: float = 1.0,
         # embed_dtype=int8, quantized) service curve instead of a
         # hand-picked default
         s, lengths = MIN_SEQ_BUCKET, []
-        while s < MAX_TOKENS:
+        while s < cfg.max_tokens:
             lengths.append(s)
             s *= 2
-        lengths.append(MAX_TOKENS)
-        mb = max_batch_for(cpu_be.platform)
+        lengths.append(cfg.max_tokens)
         bucket_fits = estimate_depth_per_bucket(
             cpu_profile, slo, lengths,
-            probe_points=probe_points(mb, cpu_be.min_batch_bucket)[:3])
+            probe_points=probe_points(max_batch(cpu_be),
+                                      cpu_be.min_batch_bucket)[:3])
         policy_obj = LengthAwarePolicy.from_bucket_depths(
             {b: d for b, (d, _) in bucket_fits.items()})
         print("[serve] per-bucket depths: "
@@ -304,7 +358,7 @@ def build_engine(model: str = "bge-large-zh-v1.5", slo: float = 1.0,
     tiers = list(main_tiers)
     if d_cpu > 0:
         tiers.append(TierSpec(CPU, d_cpu, backend=cpu_be,
-                              max_batch=max_batch_for(cpu_be.platform),
+                              max_batch=max_batch(cpu_be),
                               bucket_fn=bucket_fn))
     # --opt cache=N[,cache_bytes=M]: the zero-cost tier at the head of the
     # topology — exact-match hits bypass every device queue entirely

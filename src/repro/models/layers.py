@@ -79,7 +79,7 @@ def dense_apply(p: Params, name: str, x: jax.Array,
 
 def init_norm(cfg: ModelConfig, dtype) -> Params:
     p = {"scale": jnp.ones((cfg.d_model,), dtype)}
-    if cfg.norm == "layernorm":
+    if cfg.norm == "layernorm" and cfg.norm_bias:
         p["bias"] = jnp.zeros((cfg.d_model,), dtype)
     return p
 
@@ -90,7 +90,9 @@ def apply_norm(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         mu = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.var(xf, axis=-1, keepdims=True)
         y = (xf - mu) * lax.rsqrt(var + cfg.norm_eps)
-        y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
+        y = y * p["scale"].astype(jnp.float32)
+        if "bias" in p:
+            y = y + p["bias"].astype(jnp.float32)
     else:
         ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
         y = xf * lax.rsqrt(ms + cfg.norm_eps) * p["scale"].astype(jnp.float32)
@@ -281,6 +283,56 @@ def flash_attention_jnp(
     return out.astype(q.dtype)
 
 
+def banded_attention_jnp(q: jax.Array, k: jax.Array, v: jax.Array,
+                         window: int,
+                         kv_mask: Optional[jax.Array] = None) -> jax.Array:
+    """Bidirectional attention over the band |i - j| <= ``window``, pure JAX.
+
+    q: (B, S, H, hd); k, v: (B, S, KV, hd); ``kv_mask`` (B, S) as in
+    ``flash_attention_jnp``.  Queries go in chunks of ``window`` rows, each
+    against the keys of its own chunk and the chunk on either side (3 x
+    ``window`` keys, which hold its band), so the work grows with S x
+    window and not with S^2, and no loop is left for the compiler to
+    count once."""
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    w = window
+    n = -(-S // w)
+    pad = n * w - S
+    valid = (jnp.ones((B, S), bool) if kv_mask is None else kv_mask != 0)
+    q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    k, v = (jnp.pad(t, ((0, 0), (w, pad + w), (0, 0), (0, 0)))
+            for t in (k, v))
+    valid = jnp.pad(valid, ((0, 0), (w, pad + w)))
+
+    def three(t):      # (B, n + 2, w, ...) -> (B, n, 3w, ...)
+        t = t.reshape(B, n + 2, w, *t.shape[2:])
+        return jnp.concatenate([t[:, :-2], t[:, 1:-1], t[:, 2:]], axis=2)
+
+    qc = q.reshape(B, n, w, KV, G, hd)
+    k3, v3, m3 = three(k), three(v), three(valid)
+    s = jnp.einsum("bnqkgh,bnskh->bnkgqs", qc, k3,
+                   preferred_element_type=jnp.float32) / math.sqrt(hd)
+    # query c*w + i against key (c - 1)*w + j
+    band = jnp.abs(jnp.arange(3 * w)[None, :] - w
+                   - jnp.arange(w)[:, None]) <= w
+    s = jnp.where(band & m3[:, :, None, None, None, :], s, -1e30)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    o = jnp.einsum("bnkgqs,bnskh->bnkgqh", p.astype(v3.dtype), v3,
+                   preferred_element_type=jnp.float32)
+    o = o / jnp.maximum(p.sum(axis=-1)[..., None], 1e-30)
+    o = jnp.moveaxis(o, 4, 2).reshape(B, n * w, H, hd)[:, :S]
+    return o.astype(q.dtype)
+
+
+# attention over at least this many positions runs the flash kernel on a
+# TPU whatever ``FLAGS.attn_kernel`` says: the pure-JAX path writes
+# (B, H, S, S) float32 scores to memory
+LONG_SEQ = 1024
+
+
 def attn_forward(
     p: Params,
     cfg: ModelConfig,
@@ -293,13 +345,24 @@ def attn_forward(
     return_kv: bool = False,
     kv_mask: Optional[jax.Array] = None,  # (B, Skv) 1 = real key token
     act_quant: bool = False,              # W8A8 projections (quantized trees)
+    window: Optional[int] = None,
+    theta: Optional[float] = None,
 ):
     """Full-sequence attention for train / prefill / encoder / cross.
+
+    ``window`` and ``theta`` override the config's ``sliding_window`` and
+    ``rope_theta`` for one layer (an encoder with local and global layers).
+    A causal window admits keys in (i - window, i]; a bidirectional one
+    keys with |i - j| <= window.
 
     ``FLAGS.attn_kernel`` selects the implementation: the chunked pure-JAX
     flash path (baseline), or the Pallas TPU kernel
     (``repro.kernels.flash_attention``) — "auto" picks the kernel exactly
     where the computation is placed on a TPU (``repro.kernels.placement``).
+    In an encoder's self-attention, a windowed layer and a sequence of
+    ``LONG_SEQ`` positions or more take the "auto" route whatever the flag
+    says: the kernel skips the key blocks outside the band, and never
+    writes S x S scores.
     The kernel route assumes contiguous [0, S) positions (true for every
     full-sequence caller here) and turns a per-example ``kv_mask`` into
     prefix lengths, which is what the embedder's left-aligned padding
@@ -308,31 +371,37 @@ def attn_forward(
     kv_src = x if kv_x is None else kv_x
     kv_pos = positions if kv_positions is None else kv_positions
     q, k, v = _project_qkv(p, cfg, x, kv_src, act_quant=act_quant)
-    if cfg.rope_theta:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, kv_pos, cfg.rope_theta)
-    window = cfg.sliding_window if causal else 0
+    theta = cfg.rope_theta if theta is None else theta
+    if theta:
+        q = rope(q, positions, theta)
+        k = rope(k, kv_pos, theta)
+    if window is None:
+        window = cfg.sliding_window if causal else 0
 
     def jnp_route(q, k, v, *mask):
+        if window and not causal:
+            return banded_attention_jnp(q, k, v, window,
+                                        mask[0] if mask else None)
         return flash_attention_jnp(q, k, v, positions, kv_pos, causal=causal,
                                    window=window,
                                    kv_mask=mask[0] if mask else None)
 
     def kernel_route(q, k, v, *mask, interpret: bool = False):
-        from repro.kernels.flash_attention.ops import flash_attention
+        from repro.kernels.flash_attention.ops import flash_attention_tokens
         kv_len = None
         if mask:
             kv_len = jnp.sum(mask[0] != 0, axis=-1).astype(jnp.int32)
-        out = flash_attention(
-            jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
-            jnp.moveaxis(v, 1, 2), causal=causal, window=window,
+        return flash_attention_tokens(
+            q, k, v, causal=causal, window=window,
             backend="interpret" if interpret else "pallas", kv_len=kv_len)
-        return jnp.moveaxis(out, 2, 1)
 
     from repro.perf_flags import FLAGS
 
     args = (q, k, v) + (() if kv_mask is None else (kv_mask,))
     backend = FLAGS.attn_kernel
+    encoder = not causal and kv_x is None
+    if backend == "jnp" and encoder and (window or x.shape[1] >= LONG_SEQ):
+        backend = "auto"
     if backend == "auto":
         from repro.kernels.placement import by_placement
         out = by_placement(kernel_route, jnp_route, *args)
@@ -535,7 +604,7 @@ def cross_decode(p: Params, cfg: ModelConfig, x1, cross_k, cross_v, kv_len):
 
 
 # ----------------------------------------------------------------------------
-# MLP (SwiGLU or GELU)
+# MLP (SwiGLU, GeGLU or GELU)
 # ----------------------------------------------------------------------------
 
 def init_mlp(key, cfg: ModelConfig, dtype) -> Params:
@@ -547,8 +616,10 @@ def init_mlp(key, cfg: ModelConfig, dtype) -> Params:
             "w_up": _dense_init(ks[1], (D, F), dtype),
             "w_down": _dense_init(ks[2], (F, D), dtype),
         }
+    # GeGLU: one input projection to (input, gate), as ModernBERT's ``Wi``
+    width = 2 * F if cfg.act == "geglu" else F
     return {
-        "w_in": _dense_init(ks[0], (D, F), dtype),
+        "w_in": _dense_init(ks[0], (D, width), dtype),
         "w_out": _dense_init(ks[1], (F, D), dtype),
     }
 
@@ -559,7 +630,12 @@ def apply_mlp(p: Params, cfg: ModelConfig, x: jax.Array,
         g = jax.nn.silu(dense_apply(p, "w_gate", x, act_quant=act_quant))
         u = dense_apply(p, "w_up", x, act_quant=act_quant)
         return dense_apply(p, "w_down", g * u, act_quant=act_quant)
-    h = jax.nn.gelu(dense_apply(p, "w_in", x, act_quant=act_quant))
+    h = dense_apply(p, "w_in", x, act_quant=act_quant)
+    if cfg.act == "geglu":
+        inp, gate = jnp.split(h, 2, axis=-1)
+        h = jax.nn.gelu(inp, approximate=False) * gate
+    else:
+        h = jax.nn.gelu(h)
     return dense_apply(p, "w_out", h, act_quant=act_quant)
 
 

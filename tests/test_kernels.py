@@ -29,6 +29,8 @@ FLASH_CASES = [
     (2, 8, 2, 200, 200, 128, True, 64),   # sliding window + padding
     (1, 2, 1, 96, 96, 32, True, 0),       # small head_dim, padding
     (1, 6, 3, 130, 257, 64, True, 0),     # both dims ragged
+    (2, 4, 4, 300, 300, 64, False, 16),   # bidirectional band + padding
+    (1, 16, 16, 384, 384, 64, False, 64),  # ModernBERT's band, 16 heads
 ]
 
 
@@ -78,6 +80,34 @@ def test_flash_attention_kv_len_vs_ref(case, dtype):
     np.testing.assert_allclose(np.asarray(gj, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol(dtype), rtol=tol(dtype))
+
+
+@pytest.mark.parametrize("window,blocks", [(16, (64, 64, 1)),
+                                           (64, (128, 128, 8)),
+                                           (0, (128, 256, 2))])
+def test_token_major_kernel_and_band_paths_mask_ragged_rows(window, blocks):
+    """The token-major kernel and the pure-JAX paths, with a symmetric band
+    and per-example valid keys, agree with the oracle on every real row."""
+    from repro.kernels.flash_attention import (flash_attention_tokens,
+                                               flash_attention_tokens_pallas)
+
+    B, S, H, hd = 2, 300, 4, 64
+    ks = jax.random.split(KEY, 3)
+    q, k, v = (jax.random.normal(x, (B, S, H, hd)) for x in ks)
+    n = jnp.asarray([300, 170], jnp.int32)
+    t = lambda x: jnp.moveaxis(x, 1, 2)          # noqa: E731
+    real = (jnp.arange(S)[None, :, None, None] < n[:, None, None, None])
+    ref = jnp.where(real, t(attention_ref(t(q), t(k), t(v), causal=False,
+                                          window=window, kv_len=n)), 0)
+    bq, bk, bh = blocks
+    got = flash_attention_tokens_pallas(q, k, v, causal=False, window=window,
+                                        kv_len=n, interpret=True, block_q=bq,
+                                        block_k=bk, block_h=bh)
+    jnp_path = flash_attention_tokens(q, k, v, causal=False, window=window,
+                                      kv_len=n, backend="jnp")
+    for out in (got, jnp_path):
+        np.testing.assert_allclose(np.asarray(jnp.where(real, out, 0)),
+                                   np.asarray(ref), atol=2e-5)
 
 
 def test_attn_forward_kernel_flag_matches_jnp_path():

@@ -15,10 +15,9 @@ import sys
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
-# 485 collected as of the fault-tolerance PR (deadlines, retry/failover,
-# circuit breaking, chaos fault model); small slack so a legitimate
-# parametrization tweak is not a CI incident
-FLOOR = 647
+# the count ``tests/`` collects once ModernBERT-large's program tests are
+# in (the benchmark's own tests under ``bench/tests`` are not counted here)
+FLOOR = 671
 
 
 def test_collected_test_count_never_regresses():

@@ -113,8 +113,45 @@ def test_flash_attention_compiles_for_v5e(one_chip, dtype):
     assert "tpu_custom_call" in hlo
 
 
-def _embed_step_args(dtype, bucket, params_sharding, batch_sharding):
-    cfg = get_config("bge-large-zh-v1.5")
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("window", [0, 64])
+def test_token_major_flash_attention_compiles_for_v5e_at_8192(one_chip,
+                                                             window, dtype):
+    """ModernBERT's attention at its served context: global, and the
+    band of |i - j| <= 64 with the tiles its layers use, for bf16
+    serving and for the float32 operands of the int8 and fp32 paths
+    (whose global tiles must fit VMEM at twice the bytes)."""
+    from repro.kernels.flash_attention import flash_attention_tokens_pallas
+    from repro.kernels.flash_attention.ops import block_sizes
+
+    B, S, H, hd = 8, 8192, 16, 64
+    qkv = [_spec((B, S, H, hd), dtype, one_chip) for _ in range(3)]
+    hlo = _compile(lambda q, k, v, n: flash_attention_tokens_pallas(
+                       q, k, v, causal=False, window=window, interpret=False,
+                       kv_len=n, **block_sizes(window, False,
+                                               jnp.dtype(dtype).itemsize)),
+                   *qkv, _spec((B,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def test_modernbert_embed_step_compiles_for_v5e(one_chip):
+    """A whole ModernBERT-large step at its served (8, 8192) batch: the
+    local and global attention kernels and pool_norm compile as Mosaic
+    kernels, and the step fits the chip."""
+    cfg, params, toks, mask = _embed_step_args("bf16", (8, 8192), one_chip,
+                                               one_chip, "modernbert-large")
+    compiled = jax.jit(lambda p, t, m: embedder.embed(
+        p, cfg, t, m, compute_dtype=jnp.bfloat16)).lower(
+            params, toks, mask).compile()
+    # layer 0, the scanned period's two local layers and one global, pooling
+    assert compiled.as_text().count("tpu_custom_call") == 5
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9
+
+
+def _embed_step_args(dtype, bucket, params_sharding, batch_sharding,
+                     model="bge-large-zh-v1.5"):
+    cfg = get_config(model)
     served = jax.eval_shape(lambda: serve_params(
         embedder.init_embedder(jax.random.PRNGKey(0), cfg), dtype)[0])
     params = jax.tree.map(lambda l: _spec(l.shape, l.dtype, params_sharding),
